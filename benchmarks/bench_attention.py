@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import cost_analysis
 from repro.core.formats import PAPER_FORMATS
 from repro.core.policy import transprecision_policy
 from repro.core.qtensor import encode
@@ -138,7 +137,7 @@ def collect(b=B, s=S, h=H, g=G, dh=DH, *, impls=IMPLS,
                               flash_decode_reference(qq, kk, vv, fmt, ll))
                 entry["ms_per_step"] = round(
                     _time_us(ref, q, kp, vp, lengths) / 1e3, 3)
-                cost = cost_analysis(ref.lower(q, kp, vp, lengths).compile())
+                cost = ref.lower(q, kp, vp, lengths).compile().cost_analysis()
                 entry["xla_bytes_accessed"] = int(
                     cost.get("bytes accessed", 0))
             elif on_tpu or time_interpret:

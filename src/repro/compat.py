@@ -1,49 +1,34 @@
-"""JAX version compatibility shims, resolved once at import time.
+"""The few JAX entry points this repo wraps, bound once for JAX 0.9.0.
 
-The public JAX API has renamed or moved several symbols this repo depends
-on; every call site imports the resolved name from here instead of probing
-``hasattr`` locally.  Policy: when a symbol exists under multiple names
-across the supported JAX range (see requirements.txt), this module binds
-the one the installed version provides; when a newer concept has no old
-equivalent (the ambient *abstract* mesh), it degrades to the closest older
-semantics (the thread-local *physical* mesh) so callers keep one code path.
-
-Resolved symbols:
+The repo supports exactly one JAX, the version ``requirements.txt`` pins.
+Every call site imports these names from here instead of reaching into
+``jax`` directly, so a future upgrade touches one module.
 
 ``CompilerParams``
-    ``pltpu.CompilerParams`` (new) or ``pltpu.TPUCompilerParams``
-    (<= 0.4.x).  Same constructor signature for the fields we use
-    (``dimension_semantics``, ``vmem_limit_bytes``).
+    ``pltpu.CompilerParams`` (``dimension_semantics``, ``vmem_limit_bytes``).
 
 ``shard_map``
-    ``jax.shard_map`` (new) or ``jax.experimental.shard_map.shard_map``.
-    Both accept ``(f, mesh=..., in_specs=..., out_specs=...)``.  The
-    replication-check kwarg was renamed across versions (``check_rep`` ->
-    ``check_vma``); callers always pass ``check_rep`` and this module
-    translates to whatever the installed version accepts (needed to run
-    ``pallas_call`` bodies inside shard_map, which have no replication
-    rule).
+    ``jax.shard_map``.  Callers that run ``pallas_call`` bodies inside pass
+    ``check_vma=False`` (a Pallas kernel has no replication rule).
 
 ``get_abstract_mesh()``
-    Newer JAX returns the ambient abstract mesh set by
-    ``jax.sharding.set_mesh``.  On older versions this falls back to the
-    thread-local physical mesh activated by ``with mesh:`` (or ``None``
-    when no mesh is active).  Either return value supports ``.axis_names``,
-    ``.shape`` and can be passed to :func:`shard_map`.
+    The ambient abstract mesh set by :func:`use_mesh`, or ``None`` when no
+    mesh (or an empty one) is active.
 
 ``get_ambient_mesh()``
-    Like :func:`get_abstract_mesh` but additionally falls back to the
-    thread-local physical mesh on *newer* JAX too, so a classic
-    ``with mesh:`` block is visible to mesh-sensitive callers on every
-    supported version.
+    Like :func:`get_abstract_mesh`, but also sees a mesh activated by a
+    classic ``with mesh:`` block (the thread-local *physical* mesh), so
+    mesh-sensitive callers behave the same under either idiom.
 
 ``make_mesh(axis_shapes, axis_names, axis_types=None)``
-    Forwards ``axis_types`` only where supported (the older API has no
-    explicit/auto axis distinction -- every axis behaves as Auto).
+    ``jax.make_mesh`` with **Auto** axes by default.  JAX 0.9 defaults to
+    Explicit axes, under which the model's sharding-agnostic code
+    (``jnp.take``, ``jnp.repeat``, Pallas interpret loops) raises
+    ``ShardingTypeError``; the repo's meshes all mean GSPMD-style Auto
+    partitioning plus explicit ``shard_map`` regions.
 
 ``use_mesh(mesh)``
-    Context manager making ``mesh`` ambient: ``jax.sharding.set_mesh`` on
-    newer JAX, the plain ``Mesh`` context manager otherwise.
+    ``jax.sharding.set_mesh`` as a context manager: makes ``mesh`` ambient.
 """
 from __future__ import annotations
 
@@ -51,108 +36,38 @@ import jax
 from jax.experimental.pallas import tpu as _pltpu
 
 __all__ = [
-    "CompilerParams", "cost_analysis", "get_abstract_mesh",
-    "get_ambient_mesh", "make_mesh", "shard_map", "use_mesh",
+    "CompilerParams", "get_abstract_mesh", "get_ambient_mesh", "make_mesh",
+    "shard_map", "use_mesh",
 ]
 
-# -- Pallas TPU compiler params (renamed TPUCompilerParams -> CompilerParams)
-CompilerParams = getattr(_pltpu, "CompilerParams", None)
-if CompilerParams is None:
-    CompilerParams = _pltpu.TPUCompilerParams
-
-# -- shard_map graduated from jax.experimental to the top-level namespace;
-#    its replication-check kwarg was renamed check_rep -> check_vma
-_shard_map_raw = getattr(jax, "shard_map", None)
-if _shard_map_raw is None:
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-
-import inspect as _inspect
-
-_SHARD_MAP_CHECK_KW = (
-    "check_rep"
-    if "check_rep" in _inspect.signature(_shard_map_raw).parameters
-    else "check_vma")
-
-
-def shard_map(f, **kw):
-    if "check_rep" in kw and _SHARD_MAP_CHECK_KW != "check_rep":
-        kw[_SHARD_MAP_CHECK_KW] = kw.pop("check_rep")
-    return _shard_map_raw(f, **kw)
+CompilerParams = _pltpu.CompilerParams
+shard_map = jax.shard_map
+use_mesh = jax.sharding.set_mesh
 
 
 def get_abstract_mesh():
-    """The ambient mesh model code may shard over, or ``None``.
-
-    Newer JAX: the abstract mesh from ``jax.sharding.set_mesh`` (mapped to
-    ``None`` when empty).  Older JAX: the thread-local physical mesh from
-    ``with mesh:`` (again ``None`` when empty), which equally supports
-    ``.axis_names`` / ``.shape`` lookups and ``shard_map``.
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        mesh = getter()
-        if mesh is None or not mesh.axis_names:
-            return None
-        return mesh
-    from jax._src.mesh import thread_resources
-    mesh = thread_resources.env.physical_mesh
-    if mesh.empty:
+    """The ambient mesh model code may shard over, or ``None``."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or not mesh.axis_names:
         return None
     return mesh
 
 
 def get_ambient_mesh():
-    """The mesh the program is actually running under, however it was set.
-
-    :func:`get_abstract_mesh` only sees the *abstract* mesh on newer JAX,
-    so code consulting it misses a mesh activated the classic way (a plain
-    ``with mesh:`` block, which populates only the thread-local *physical*
-    mesh).  This helper checks the abstract mesh first and then falls back
-    to the thread-local physical mesh -- the same degradation this module
-    already applies wholesale on older JAX -- so mesh-sensitive decisions
-    (``dispatch.default_serving_impl``, the ``flash_shmap`` wrapper) behave
-    identically under ``jax.sharding.set_mesh`` and ``with mesh:``.
-    """
+    """The mesh the program is running under, however it was set: the
+    abstract mesh first, then the thread-local physical mesh of a plain
+    ``with mesh:`` block (``dispatch.default_serving_impl`` and the
+    sharded wrappers must see both)."""
     mesh = get_abstract_mesh()
     if mesh is not None:
         return mesh
     from jax._src.mesh import thread_resources
     pm = thread_resources.env.physical_mesh
-    if pm.empty:
-        return None
-    return pm
+    return None if pm.empty else pm
 
 
 def make_mesh(axis_shapes, axis_names, *, axis_types=None, **kw):
-    """``jax.make_mesh`` that tolerates the ``axis_types`` kwarg everywhere.
-
-    ``axis_types`` is dropped on JAX versions without explicit sharding
-    (where every mesh axis already has Auto semantics).
-    """
-    if axis_types is not None and hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = axis_types
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
-
-
-def cost_analysis(compiled):
-    """``Compiled.cost_analysis()`` as a flat dict on every JAX version.
-
-    Older JAX wraps the per-program dict in a single-element list.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
-def use_mesh(mesh):
-    """Context manager activating ``mesh`` as the ambient mesh.
-
-    Prefers ``jax.sharding.set_mesh`` (so model code can reach the abstract
-    mesh for shard_map paths); falls back to the bare ``Mesh`` context
-    manager, whose thread-local mesh :func:`get_abstract_mesh` also finds.
-    """
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """``jax.make_mesh`` whose axes default to ``AxisType.Auto``."""
+    if axis_types is None:
+        axis_types = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types, **kw)

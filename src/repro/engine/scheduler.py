@@ -80,6 +80,8 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import paged_cache
 
@@ -133,6 +135,19 @@ class Request:
         self.error = None
 
 
+def shard_pool(cache, mesh):
+    """Place a page pool with its page axis split over the mesh's ``model``
+    axis (tables and lengths replicated) -- the layout the sharded
+    wrappers' ``shard_map`` reads, so no step reshards the pool."""
+    pages = NamedSharding(mesh, P("model"))
+    rep = NamedSharding(mesh, P())
+    return cache._replace(
+        k_pool=jax.device_put(cache.k_pool, pages),
+        v_pool=jax.device_put(cache.v_pool, pages),
+        block_tables=jax.device_put(cache.block_tables, rep),
+        seq_lens=jax.device_put(cache.seq_lens, rep))
+
+
 def _insert_slot(all_states, one_states, slot: int, n_slots: int):
     """Write a 1-sequence state pytree into row ``slot`` of the batched
     state (arrays without a leading slots axis are taken wholesale)."""
@@ -177,6 +192,11 @@ class Engine:
     watchdog_s / watchdog_limit: wall-clock budget per engine step; after
         ``watchdog_limit`` consecutive over-budget steps the run raises a
         classified ``WatchdogTimeout`` (None = watchdog off).
+    mesh: the ``"model"`` mesh a sharded (``flash_shmap+`` / ``ring+``)
+        spelling serves under.  Params are replicated over it and every
+        page pool is placed with its page axis sharded; a mesh the
+        wrappers could not shard over raises instead of serving on one
+        device.  None (the default) keeps everything on device 0.
     """
 
     def __init__(self, model, cfg, policy, params, *, slots: int,
@@ -193,7 +213,8 @@ class Engine:
                  retry_policy: Optional[resilience.RetryPolicy] = None,
                  breaker: Optional[resilience.CircuitBreaker] = None,
                  watchdog_s: Optional[float] = None,
-                 watchdog_limit: int = 3):
+                 watchdog_limit: int = 3,
+                 mesh=None):
         self.model, self.cfg, self.policy = model, cfg, policy
         self.calibration_tap = calibration_tap
         self.params = params
@@ -226,6 +247,10 @@ class Engine:
                                          self.pages_per_seq)
         self.stats = stats if stats is not None else EngineStats()
         self.device = jax.devices()[0]
+        self.mesh = mesh
+        if mesh is not None:
+            self._check_mesh(mesh)
+            self.params = jax.device_put(params, NamedSharding(mesh, P()))
 
         self.injector = FaultInjector(fault_plan, self.stats)
         self.retry_policy = (retry_policy if retry_policy is not None
@@ -242,6 +267,8 @@ class Engine:
             states[li] = paged_cache.init_paged_cache(
                 slots, self.num_pages, page, self.pages_per_seq, cfg.n_kv,
                 cfg.head_dim, policy.dtype("kv_cache", layer=li))
+            if mesh is not None:
+                states[li] = shard_pool(states[li], mesh)
         self.states = states
 
         if transport is None:
@@ -309,6 +336,24 @@ class Engine:
         self._finalized = False
 
     # ------------------------------------------------------------------ utils
+    def _check_mesh(self, mesh) -> None:
+        """A sharded spelling needs a model axis of > 1 device that divides
+        the axis its wrapper shards (the pool's pages for a paged base, the
+        gathered sequence for contiguous bases).  The wrappers themselves
+        run unsharded when it does not, which would serve on one device
+        while the caller asked for many -- so refuse here, loudly."""
+        n = mesh.shape.get("model", 1)
+        if n < 2:
+            raise ValueError(
+                f"a sharded decode spelling needs a 'model' mesh axis of at "
+                f"least 2 devices, got {dict(mesh.shape)}")
+        if self.num_pages % n or (self.pages_per_seq * self.page) % n:
+            raise ValueError(
+                f"pool pages ({self.num_pages}) and per-slot capacity "
+                f"({self.pages_per_seq * self.page} tokens) must both divide "
+                f"over the {n}-device 'model' axis; adjust --pool-pages / "
+                f"--capacity")
+
     def _push_tables(self, mask_slots=()) -> None:
         """Mirror the host block tables onto the device; ``mask_slots``
         hides the mid-prefill slots from the decode step (-1 rows drop

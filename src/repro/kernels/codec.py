@@ -142,7 +142,9 @@ def _int_times_pow2_bits(i, qe):
       f32-denormal result: i << (qe + 149)
     """
     thresh = np.uint32(1) << max(0, min(-126 - qe, 23))
-    as_f = i.astype(jnp.float32)  # exact: i <= 2^23 after rounding
+    # exact: i <= 2^23 after rounding.  Through int32, because the TPU
+    # lowering has no unsigned -> float conversion.
+    as_f = i.astype(jnp.int32).astype(jnp.float32)
     norm_bits = (bits32(as_f).astype(jnp.int32) + np.int32(qe << 23)
                  ).astype(_U32)
     den_bits = i << np.uint32(max(qe + 149, 0))

@@ -390,7 +390,7 @@ def _shmap_decode(inner, mesh, q, ck, cv, n_valid, *, scale, policy):
         out_specs=P(bspec, None, None, None),
         # pallas_call has no replication rule; the collectives above
         # make the output replicated by construction
-        check_rep=False,
+        check_vma=False,
     )(q, ck, cv, n_valid)
 
 
@@ -425,7 +425,7 @@ def _shmap_decode_paged(inner, mesh, q, ck, cv, n_valid, block_tables, *,
                   P(bspec),
                   P(bspec, None)),                # tables replicated/model
         out_specs=P(bspec, None, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, ck, cv, n_valid, block_tables)
 
 
@@ -522,7 +522,7 @@ def _ring_decode(inner, mesh, q, ck, cv, n_valid, *, scale, policy):
         out_specs=P(bspec, None, None, None),
         # pallas_call has no replication rule; after n_model folds the
         # output is replicated by construction
-        check_rep=False,
+        check_vma=False,
     )(q, ck, cv, n_valid)
 
 
@@ -567,7 +567,7 @@ def _ring_decode_paged(inner, mesh, q, ck, cv, n_valid, block_tables, *,
                   P(bspec),
                   P(bspec, None)),                # tables replicated
         out_specs=P(bspec, None, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, ck, cv, n_valid, block_tables)
 
 

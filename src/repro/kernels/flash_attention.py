@@ -25,10 +25,12 @@ Kernels
 -------
 ``flash_decode``
     One query token per sequence against a packed KV cache of capacity S.
-    Grid (B, H, S/block_kv); a VMEM running (max, sum, acc) triple carries
-    the online softmax across KV tiles.  Ragged per-sequence lengths mask
-    invalid slots, which also covers the sliding-window ring buffer (every
-    written slot is valid; order is irrelevant under softmax).
+    Grid (B, S/block_kv); each KV tile holds all H heads (the head loop
+    runs in the body) and a VMEM running (max, sum, acc) triple per head
+    carries the online softmax across KV tiles.  Ragged per-sequence
+    lengths mask invalid slots, which also covers the sliding-window ring
+    buffer (every written slot is valid; order is irrelevant under
+    softmax).
 
 ``flash_prefill``
     Chunked causal prefill: grid (B, H, Sq/block_q, Skv/block_kv), KV
@@ -41,9 +43,9 @@ Numerics
 Softmax statistics and both dots accumulate in f32 (the FlexFloat "compute
 wide" contract).  ``flash_decode_reference`` is the XLA dequantize oracle:
 it mirrors the kernel's operation order exactly (decode -> QK^T -> exp with
-running max -> PV / sum), so in interpret mode kernel and oracle agree to a
-few ulp (bit-exact when one KV tile covers the cache); tests assert this for
-all four paper formats.
+running max -> PV / sum), so kernel and oracle differ only in the f32
+summation order the compiler picks; tests bound that difference for all
+four paper formats.
 
 Integration
 -----------
@@ -115,7 +117,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, *refs,
         o_ref, mo_ref, lo_ref, acc_ref, m_ref, l_ref = refs
     else:
         (o_ref, acc_ref, m_ref, l_ref), mo_ref, lo_ref = refs, None, None
-    si = pl.program_id(2)
+    si = pl.program_id(1)
 
     @pl.when(si == 0)
     def _init():
@@ -123,20 +125,53 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, *refs,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                    # (Gp, dh)
-    k = _payload_to_f32(k_ref[0, :, 0], fmt)               # (bkv, dh)
-    v = _payload_to_f32(v_ref[0, :, 0], fmt)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    pos = si * block_kv + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    _online_update(s, v, acc_ref, m_ref, l_ref, pos < len_ref[0, 0])
+    pos = si * block_kv + jax.lax.broadcasted_iota(
+        jnp.int32, (q_ref.shape[1], block_kv), 1)
+    _fold_heads(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, fmt, scale,
+                pos < len_ref[pl.program_id(0)])
 
     @pl.when(si == n_kv - 1)
     def _flush():
-        o_ref[0, 0] = _finalize(acc_ref, l_ref)
-        if with_residuals:
-            mo_ref[0, 0] = m_ref[...]
-            lo_ref[0, 0] = l_ref[...]
+        _flush_heads(o_ref, mo_ref, lo_ref, acc_ref, m_ref, l_ref)
+
+
+def _fold_heads(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, fmt, scale,
+                mask):
+    """Fold one KV tile (bkv, H, dh) into every head's running softmax.
+
+    The tile carries all H heads: a block that squeezed the head axis out
+    would put a 1 against H in the second-minor (sublane) position, which
+    the TPU lowering refuses; H equals the array's own dim, so the whole-
+    head block tiles legally and one DMA moves a contiguous slab."""
+    # decode the whole tile once, then slice heads out of the f32 value:
+    # decoding per-head strided loads compiles about 8x slower
+    k = _payload_to_f32(k_ref[...], fmt)                   # (bkv, H, dh)
+    v = _payload_to_f32(v_ref[...], fmt)
+    for h in range(k.shape[1]):
+        q = q_ref[h].astype(jnp.float32)                   # (Gp, dh)
+        s = jax.lax.dot_general(q, k[:, h, :], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        _online_update(s, v[:, h, :], acc_ref.at[h], m_ref.at[h],
+                       l_ref.at[h], mask)
+
+
+def _flush_heads(o_ref, mo_ref, lo_ref, acc_ref, m_ref, l_ref):
+    for h in range(o_ref.shape[0]):
+        o_ref[h] = _finalize(acc_ref.at[h], l_ref.at[h])
+    if mo_ref is not None:
+        mo_ref[...] = m_ref[...]
+        lo_ref[...] = l_ref[...]
+
+
+def _decode_layout(H: int, Gp: int, dh: int, return_residuals: bool):
+    """Output lane widths (o, then m and l with residuals) and VMEM scratch
+    shared by the contiguous and paged decode kernels: every grid step
+    owns one sequence's whole (H, Gp, ...) query/output block."""
+    scratch = [pltpu.VMEM((H, Gp, dh), jnp.float32),
+               pltpu.VMEM((H, Gp, 128), jnp.float32),
+               pltpu.VMEM((H, Gp, 128), jnp.float32)]
+    widths = [dh] + ([128, 128] if return_residuals else [])
+    return widths, scratch
 
 
 def flash_decode(q, k_payload, v_payload, fmt, lengths, *,
@@ -179,42 +214,34 @@ def flash_decode(q, k_payload, v_payload, fmt, lengths, *,
     # clamp: callers may pass a running token count that exceeds capacity
     # (decode past a full non-window cache); without this the padded slots
     # [S, S+ps) would count as valid and dilute the softmax
-    lengths = jnp.minimum(lengths.astype(jnp.int32), S).reshape(B, 1)
+    lengths = jnp.minimum(lengths.astype(jnp.int32), S)
 
     kern = functools.partial(_decode_kernel, fmt=fmt,
                              scale=np.float32(scale), block_kv=bkv, n_kv=n_kv,
                              with_residuals=return_residuals)
-    out_specs = [pl.BlockSpec((1, 1, Gp, dh), lambda b, h, s: (b, h, 0, 0))]
-    out_shape = [jax.ShapeDtypeStruct((B, H, Gp, dh), jnp.float32)]
-    if return_residuals:
-        out_specs += [pl.BlockSpec((1, 1, Gp, 128),
-                                   lambda b, h, s: (b, h, 0, 0))] * 2
-        out_shape += [jax.ShapeDtypeStruct((B, H, Gp, 128), jnp.float32)] * 2
+    widths, scratch = _decode_layout(H, Gp, dh, return_residuals)
+    qmap = lambda b, s: (b, 0, 0, 0)                        # noqa: E731
     out = pl.pallas_call(
         kern,
-        grid=(B, H, n_kv),
+        grid=(B, n_kv),
         in_specs=[
-            pl.BlockSpec((1, 1, Gp, dh), lambda b, h, s: (b, h, 0, 0)),
-            pl.BlockSpec((1, bkv, 1, dh), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, bkv, 1, dh), lambda b, h, s: (b, s, h, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, s: (b, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, H, Gp, dh), qmap),
+            pl.BlockSpec((None, bkv, H, dh), lambda b, s: (b, s, 0, 0)),
+            pl.BlockSpec((None, bkv, H, dh), lambda b, s: (b, s, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # all (B,) lengths
         ],
-        out_specs=out_specs if return_residuals else out_specs[0],
-        out_shape=out_shape if return_residuals else out_shape[0],
-        scratch_shapes=[
-            pltpu.VMEM((Gp, dh), jnp.float32),
-            pltpu.VMEM((Gp, 128), jnp.float32),
-            pltpu.VMEM((Gp, 128), jnp.float32),
-        ],
+        out_specs=[pl.BlockSpec((None, H, Gp, w), qmap) for w in widths],
+        out_shape=[jax.ShapeDtypeStruct((B, H, Gp, w), jnp.float32)
+                   for w in widths],
+        scratch_shapes=scratch,
         compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k_payload, v_payload, lengths)
     if return_residuals:
         o, m, l = out
         return o[:, :, :G, :], m[:, :, :G, 0], l[:, :, :G, 0]
-    return out[:, :, :G, :]
+    return out[0][:, :, :G, :]
 
 
 def flash_decode_reference(q, k_payload, v_payload, fmt, lengths, *,
@@ -281,11 +308,11 @@ def _prefill_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(live)
     def _update():
         bq = block_q
-        q = q_ref[0, :, 0].astype(jnp.float32)             # (bq, Gp, dh)
+        q = q_ref[...].astype(jnp.float32)                 # (bq, Gp, dh)
         gp, dh = q.shape[1], q.shape[2]
         q2 = q.reshape(bq * gp, dh)
-        k = _payload_to_f32(k_ref[0, :, 0], fmt)           # (bkv, dh)
-        v = _payload_to_f32(v_ref[0, :, 0], fmt)
+        k = _payload_to_f32(k_ref[...], fmt)               # (bkv, dh)
+        v = _payload_to_f32(v_ref[...], fmt)
         s = jax.lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -300,9 +327,7 @@ def _prefill_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(si == n_kv - 1)
     def _flush():
-        o_ref[0, :, 0] = _finalize(acc_ref, l_ref).reshape(o_ref.shape[1],
-                                                           o_ref.shape[3],
-                                                           o_ref.shape[4])
+        o_ref[...] = _finalize(acc_ref, l_ref).reshape(o_ref.shape)
 
 
 def flash_prefill(q, k_payload, v_payload, fmt=None, *,
@@ -345,6 +370,13 @@ def flash_prefill(q, k_payload, v_payload, fmt=None, *,
         k_payload = jnp.pad(k_payload, ((0, 0), (0, ps), (0, 0), (0, 0)))
         v_payload = jnp.pad(v_payload, ((0, 0), (0, ps), (0, 0), (0, 0)))
     n_q, n_kv = (Sq + pq) // bq, (Skv + ps) // bkv
+    # head-major K/V: a (bkv, dh) block of a (B, H, Skv, dh) array tiles
+    # legally, where a head-squeezed block of the (B, Skv, H, dh) layout
+    # would put a 1 against H in the sublane position (refused by the TPU
+    # lowering).  Prefill K/V are a fresh chunk or a gathered copy, so the
+    # transpose adds one pass over bytes already being copied.
+    k_payload = jnp.swapaxes(k_payload, 1, 2)
+    v_payload = jnp.swapaxes(v_payload, 1, 2)
 
     kern = functools.partial(
         _prefill_kernel, fmt=fmt, scale=np.float32(scale), block_q=bq,
@@ -354,12 +386,14 @@ def flash_prefill(q, k_payload, v_payload, fmt=None, *,
         kern,
         grid=(B, H, n_q, n_kv),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, Gp, dh),
+            pl.BlockSpec((None, bq, None, Gp, dh),
                          lambda b, h, i, s: (b, i, h, 0, 0)),
-            pl.BlockSpec((1, bkv, 1, dh), lambda b, h, i, s: (b, s, h, 0)),
-            pl.BlockSpec((1, bkv, 1, dh), lambda b, h, i, s: (b, s, h, 0)),
+            pl.BlockSpec((None, None, bkv, dh),
+                         lambda b, h, i, s: (b, h, s, 0)),
+            pl.BlockSpec((None, None, bkv, dh),
+                         lambda b, h, i, s: (b, h, s, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, Gp, dh),
+        out_specs=pl.BlockSpec((None, bq, None, Gp, dh),
                                lambda b, h, i, s: (b, i, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Sq + pq, H, Gp, dh), jnp.float32),
         scratch_shapes=[

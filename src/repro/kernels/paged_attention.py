@@ -14,14 +14,15 @@ codec (``codec.decode_tile`` via ``flash_attention._payload_to_f32``), so
 HBM still moves container-width bytes: the paper's 4x byte win survives
 non-contiguous caches.
 
-Grid: (B, H, pages_per_seq), pages innermost ("arbitrary") carrying the
-running (max, sum, acc) online-softmax triple, exactly like the contiguous
-kernel with ``block_kv = page_size``.  Masking is two-level: positions at
-or past ``lengths[b]`` are invalid, and *unmapped* pages (table entry < 0)
-are masked wholesale -- which is also what makes the pool shardable: the
-``flash_shmap+paged`` wrapper in ``kernels/dispatch.py`` gives every device
-the pool shard it owns plus a table with non-owned pages set to -1, and
-merges the per-device partials (m, l) exactly as for the contiguous case.
+Grid: (B, pages_per_seq), pages innermost ("arbitrary") carrying every
+head's running (max, sum, acc) online-softmax triple, exactly like the
+contiguous kernel with ``block_kv = page_size``.  Masking is two-level:
+positions at or past ``lengths[b]`` are invalid, and *unmapped* pages
+(table entry < 0) are masked wholesale -- which is also what makes the
+pool shardable: the ``flash_shmap+paged`` wrapper in
+``kernels/dispatch.py`` gives every device the pool shard it owns plus a
+table with non-owned pages set to -1, and merges the per-device partials
+(m, l) exactly as for the contiguous case.
 
 ``paged_decode_reference`` is the XLA oracle: gather the pool through the
 block table (materializing the contiguous wide copy the kernel avoids),
@@ -44,8 +45,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.compat import CompilerParams
 from repro.core.formats import get_format
 
-from .flash_attention import (NEG_INF, _MIN_SUBLANE, _finalize,
-                              _online_update, _payload_to_f32)
+from .flash_attention import (NEG_INF, _MIN_SUBLANE, _decode_layout,
+                              _flush_heads, _fold_heads, _payload_to_f32)
 from .paged_cache import gather_pages
 
 
@@ -55,7 +56,7 @@ def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, *refs,
         o_ref, mo_ref, lo_ref, acc_ref, m_ref, l_ref = refs
     else:
         (o_ref, acc_ref, m_ref, l_ref), mo_ref, lo_ref = refs, None, None
-    b, pi = pl.program_id(0), pl.program_id(2)
+    b, pi = pl.program_id(0), pl.program_id(1)
 
     @pl.when(pi == 0)
     def _init():
@@ -63,24 +64,17 @@ def _paged_decode_kernel(len_ref, tbl_ref, q_ref, k_ref, v_ref, *refs,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                    # (Gp, dh)
-    k = _payload_to_f32(k_ref[0, :, 0], fmt)               # (page, dh)
-    v = _payload_to_f32(v_ref[0, :, 0], fmt)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    pos = pi * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    pos = pi * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, (q_ref.shape[1], page_size), 1)
     # two-level validity: ragged length AND page actually mapped (unmapped
     # pages -- free slots, table tails, non-owned shards -- are fetched as
     # a clamped placeholder and must not contribute)
     mask = (pos < len_ref[b]) & (tbl_ref[b, pi] >= 0)
-    _online_update(s, v, acc_ref, m_ref, l_ref, mask)
+    _fold_heads(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, fmt, scale, mask)
 
     @pl.when(pi == n_pages - 1)
     def _flush():
-        o_ref[0, 0] = _finalize(acc_ref, l_ref)
-        if with_residuals:
-            mo_ref[0, 0] = m_ref[...]
-            lo_ref[0, 0] = l_ref[...]
+        _flush_heads(o_ref, mo_ref, lo_ref, acc_ref, m_ref, l_ref)
 
 
 def paged_decode(q, k_pool, v_pool, fmt, lengths, block_tables, *,
@@ -125,44 +119,36 @@ def paged_decode(q, k_pool, v_pool, fmt, lengths, block_tables, *,
                              with_residuals=return_residuals)
     # index maps receive (grid ids..., *scalar-prefetch refs); the pool
     # block index is the block-table lookup itself, clamped so unmapped
-    # entries fetch page 0 (fully masked in the kernel body)
-    qmap = lambda b, h, p, lens, tbl: (b, h, 0, 0)          # noqa: E731
-    pmap = lambda b, h, p, lens, tbl: (                     # noqa: E731
-        jnp.maximum(tbl[b, p], 0), 0, h, 0)
-    in_specs = [
-        pl.BlockSpec((1, 1, Gp, dh), qmap),
-        pl.BlockSpec((1, page, 1, dh), pmap),
-        pl.BlockSpec((1, page, 1, dh), pmap),
-    ]
-    out_specs = [pl.BlockSpec((1, 1, Gp, dh), qmap)]
-    out_shape = [jax.ShapeDtypeStruct((B, H, Gp, dh), jnp.float32)]
-    if return_residuals:
-        rmap = lambda b, h, p, lens, tbl: (b, h, 0, 0)      # noqa: E731
-        out_specs += [pl.BlockSpec((1, 1, Gp, 128), rmap)] * 2
-        out_shape += [jax.ShapeDtypeStruct((B, H, Gp, 128), jnp.float32)] * 2
+    # entries fetch page 0 (fully masked in the kernel body).  One block
+    # is a whole page with all H heads: one contiguous DMA per page.
+    qmap = lambda b, p, lens, tbl: (b, 0, 0, 0)             # noqa: E731
+    pmap = lambda b, p, lens, tbl: (                        # noqa: E731
+        jnp.maximum(tbl[b, p], 0), 0, 0, 0)
+    widths, scratch = _decode_layout(H, Gp, dh, return_residuals)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, n_pages),
-        in_specs=in_specs,
-        out_specs=out_specs if return_residuals else out_specs[0],
-        scratch_shapes=[
-            pltpu.VMEM((Gp, dh), jnp.float32),
-            pltpu.VMEM((Gp, 128), jnp.float32),
-            pltpu.VMEM((Gp, 128), jnp.float32),
+        grid=(B, n_pages),
+        in_specs=[
+            pl.BlockSpec((None, H, Gp, dh), qmap),
+            pl.BlockSpec((None, page, H, dh), pmap),
+            pl.BlockSpec((None, page, H, dh), pmap),
         ],
+        out_specs=[pl.BlockSpec((None, H, Gp, w), qmap) for w in widths],
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=out_shape if return_residuals else out_shape[0],
+        out_shape=[jax.ShapeDtypeStruct((B, H, Gp, w), jnp.float32)
+                   for w in widths],
         compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, tables, q, k_pool, v_pool)
     if return_residuals:
         o, m, l = out
         return o[:, :, :G, :], m[:, :, :G, 0], l[:, :, :G, 0]
-    return out[:, :, :G, :]
+    return out[0][:, :, :G, :]
 
 
 def paged_decode_reference(q, k_pool, v_pool, fmt, lengths, block_tables, *,

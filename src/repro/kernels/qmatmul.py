@@ -83,7 +83,7 @@ def _apply_act(x, name: str):
 
 
 def _qmm_kernel(*refs, fmt_a, fmt_b, gated, has_bias, act, out_em, n_k,
-                out_dtype):
+                k_axis, out_dtype):
     it = iter(refs)
     a_ref = next(it)
     b_ref = next(it)
@@ -93,7 +93,7 @@ def _qmm_kernel(*refs, fmt_a, fmt_b, gated, has_bias, act, out_em, n_k,
     acc_ref = next(it)
     acc2_ref = next(it) if gated else None
 
-    k = pl.program_id(2)
+    k = pl.program_id(k_axis)
 
     @pl.when(k == 0)
     def _zero():
@@ -133,7 +133,10 @@ def qmatmul(a_payload, b_payload, fmt_a, fmt_b,
 
     ``a_payload``/``b_payload`` are packed containers (from
     ``core.qtensor.encode``) when ``fmt_a``/``fmt_b`` are given, or plain
-    float arrays when the corresponding format is None.
+    float arrays when the corresponding format is None.  With a leading
+    group axis -- (E, M, K) @ (E, K, N), the MoE experts -- every group
+    is one more grid axis of the SAME kernel: one Mosaic program per
+    grouped matmul instead of one per expert.
 
     Fused epilogue (all optional, applied in this order at the final K
     step): ``+ bias`` (shape (N,)), nonlinearity ``act`` ("silu" | "gelu" |
@@ -153,7 +156,10 @@ def qmatmul(a_payload, b_payload, fmt_a, fmt_b,
     gated = gate_payload is not None
     has_bias = bias is not None
 
-    (M, K), (K2, N) = a_payload.shape, b_payload.shape
+    groups = a_payload.shape[:-2]
+    assert groups == b_payload.shape[:-2] and len(groups) <= 1, (
+        a_payload.shape, b_payload.shape)
+    (M, K), (K2, N) = a_payload.shape[-2:], b_payload.shape[-2:]
     assert K == K2, (a_payload.shape, b_payload.shape)
     if gated:
         assert gate_payload.shape == b_payload.shape, (
@@ -172,48 +178,60 @@ def qmatmul(a_payload, b_payload, fmt_a, fmt_b,
     bn = _round_up(min(bn, N), _LANE)
     pm, pn, pk = _round_up(M, bm) - M, _round_up(N, bn) - N, \
         _round_up(K, bk) - K
+    g0 = ((0, 0),) * len(groups)
     if pm or pk:
-        a_payload = jnp.pad(a_payload, ((0, pm), (0, pk)))
+        a_payload = jnp.pad(a_payload, g0 + ((0, pm), (0, pk)))
     if pk or pn:
-        b_payload = jnp.pad(b_payload, ((0, pk), (0, pn)))
+        b_payload = jnp.pad(b_payload, g0 + ((0, pk), (0, pn)))
         if gated:
-            gate_payload = jnp.pad(gate_payload, ((0, pk), (0, pn)))
+            gate_payload = jnp.pad(gate_payload, g0 + ((0, pk), (0, pn)))
     Mp, Np, Kp = M + pm, N + pn, K + pk
     n_k = Kp // bk
 
+    def spec(block, index):
+        """BlockSpec over the (i, j, k) grid, behind the group axis (whose
+        block dim is squeezed) when there is one."""
+        if not groups:
+            return pl.BlockSpec(block, index)
+        return pl.BlockSpec((None,) + block,
+                            lambda g, i, j, k: (g,) + index(i, j, k))
+
     operands = [a_payload, b_payload]
     in_specs = [
-        pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-        pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
+        spec((bm, bk), lambda i, j, k: (i, k)),
+        spec((bk, bn), lambda i, j, k: (k, j)),
     ]
     if gated:
         operands.append(gate_payload)
-        in_specs.append(pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)))
+        in_specs.append(spec((bk, bn), lambda i, j, k: (k, j)))
     if has_bias:
-        assert bias.shape == (N,), (bias.shape, N)
+        assert bias.shape == (N,) and not groups, (bias.shape, N, groups)
         b2 = jnp.pad(bias.astype(jnp.float32), (0, pn)).reshape(1, Np)
         operands.append(b2)
-        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k: (0, j)))
+        in_specs.append(spec((1, bn), lambda i, j, k: (0, j)))
 
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
     if gated:
         scratch.append(pltpu.VMEM((bm, bn), jnp.float32))
 
+    grid = groups + (Mp // bm, Np // bn, n_k)
     kern = functools.partial(_qmm_kernel, fmt_a=fmt_a, fmt_b=fmt_b,
                              gated=gated, has_bias=has_bias, act=act,
-                             out_em=out_em, n_k=n_k, out_dtype=jnp.float32)
+                             out_em=out_em, n_k=n_k, k_axis=len(grid) - 1,
+                             out_dtype=jnp.float32)
     out = pl.pallas_call(
         kern,
-        grid=(Mp // bm, Np // bn, n_k),
+        grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
+        out_specs=spec((bm, bn), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct(groups + (Mp, Np), jnp.float32),
         scratch_shapes=scratch,
         compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel",) * (len(grid) - 1)
+            + ("arbitrary",)),
         interpret=interpret,
     )(*operands)
-    return out[:M, :N]
+    return out[..., :M, :N]
 
 
 def qmm_ffn(x, w_in_payload, w_gate_payload, fmt_w, *, bias=None,

@@ -243,7 +243,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         t_compile = time.time() - t0 - t_lower
 
         mem = compiled.memory_analysis()
-        cost = compat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
 
     coll = hlo_analysis.collective_stats(hlo)

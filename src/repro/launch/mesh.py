@@ -10,13 +10,13 @@ touch jax device state (the dry-run sets XLA_FLAGS before first jax init).
 """
 from __future__ import annotations
 
-import jax
+from repro import compat
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def dp_axes(mesh) -> tuple:

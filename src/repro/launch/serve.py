@@ -46,13 +46,14 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import contextlib
 import dataclasses
 import sys
 
 import jax
 import numpy as np
 
-from repro import configs
+from repro import compat, configs
 from repro.core.formats import BINARY8
 from repro.core.policy import get_policy
 from repro.tuning.artifact import load_policy
@@ -63,10 +64,18 @@ from repro.engine import (ColocatedTransport, Engine, EngineStats,
 from repro.kernels import dispatch
 from repro.launch.cli import (add_backend_args, add_resilience_args,
                               add_router_args, add_speculative_args)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import qparams
-from repro.models.registry import build
+from repro.models.registry import build, build_from_config
 
-__all__ = ["Request", "build_draft", "cli_main", "main"]
+__all__ = ["RECOVERY_COUNTERS", "Request", "build_draft", "cli_main",
+           "main", "serve"]
+
+# summary counters that are zero in a healthy run without a fault plan: a
+# non-zero value means the engine recovered from something (a NaN guard
+# trip replayed through the oracle, a retried step, a refetched page, ...)
+RECOVERY_COUNTERS = ("quarantines", "retries", "crc_mismatches",
+                     "degraded_steps", "failures", "evictions")
 
 
 def build_draft(model, cfg, *, arch=None, reduced=False, k):
@@ -90,7 +99,12 @@ def build_draft(model, cfg, *, arch=None, reduced=False, k):
     return SpeculativeDecoder(dmodel, dcfg, draft_policy, dparams, k=k)
 
 
-def main(argv=None):
+def serve(argv=None, *, n_layers=None):
+    """Parse ``argv``, build the model and engine, serve every request;
+    returns ``(engine, requests)`` so callers can read the engine's
+    summary counters and state after the run.  ``n_layers`` serves only
+    the config's first N decoder layers (every width unchanged): a depth
+    cut for checks that cannot afford the full stack's compile time."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b", choices=configs.ARCHS)
     ap.add_argument("--reduced", action="store_true")
@@ -132,6 +146,13 @@ def main(argv=None):
             policy, decode_impl=dispatch.default_serving_impl())
     impl = policy.decode_impl
     model, cfg = build(args.arch, reduced=args.reduced)
+    if n_layers:
+        if not 0 < n_layers <= cfg.n_layers:
+            raise ValueError(f"n_layers={n_layers}: {cfg.arch} has "
+                             f"{cfg.n_layers} decoder layers")
+        cfg = dataclasses.replace(cfg, n_layers=n_layers,
+                                  attn_pattern=cfg.attn_pattern[:n_layers])
+        model = build_from_config(cfg)
     effective_impl = impl or cfg.decode_impl
     if args.disaggregate and len(dispatch.canonicalize_impl(
             effective_impl)) > 1:
@@ -140,6 +161,13 @@ def main(argv=None):
             f"mesh-sharded spelling {effective_impl!r} keeps the pool "
             f"sharded across the mesh -- use a base spelling "
             f"(xla / flash_pallas / paged)")
+    mesh = None
+    if len(dispatch.canonicalize_impl(effective_impl)) > 1:
+        # a wrapped spelling shards over a 1-D "model" mesh of every
+        # device; the Engine refuses one its wrapper could not shard over
+        mesh = compat.make_mesh((len(jax.devices()),), ("model",))
+        print(f"[serve] mesh: model={mesh.shape['model']} "
+              f"({jax.devices()[0].platform})")
     params = model.init_params(jax.random.PRNGKey(0), policy)
     if (policy.matmul_impl or cfg.matmul_impl) == "qmm_pallas":
         # the packed parameter store is built ONCE at load time; every
@@ -188,19 +216,24 @@ def main(argv=None):
                     fault_plan=fault_plan,
                     deadline_steps=args.deadline_steps,
                     max_requeues=args.max_requeues,
-                    watchdog_s=args.watchdog_s)
-    if args.router:
-        # async front-end: submissions flow through the Router's queue
-        # into the same engine; a ticket's classified per-request failure
-        # comes back on the Request, engine-fatal errors raise here
-        asyncio.run(run_router(engine, reqs,
-                               max_pending=args.max_pending))
-        print(f"[serve] router: {n_workers} prefill worker(s), "
-              f"queue wait mean: {engine.summary['queue_wait_mean_s']}s, "
-              f"per-worker prefill chunks: "
-              f"{engine.summary['prefill_chunks_by_worker']}")
-    else:
-        engine.run(reqs)
+                    watchdog_s=args.watchdog_s,
+                    mesh=mesh)
+    with (compat.use_mesh(mesh) if mesh is not None
+          else contextlib.nullcontext()):
+        if args.router:
+            # async front-end: submissions flow through the Router's
+            # queue into the same engine; a ticket's classified
+            # per-request failure comes back on the Request, engine-fatal
+            # errors raise here
+            asyncio.run(run_router(engine, reqs,
+                                   max_pending=args.max_pending))
+            print(f"[serve] router: {n_workers} prefill worker(s), "
+                  f"queue wait mean: "
+                  f"{engine.summary['queue_wait_mean_s']}s, "
+                  f"per-worker prefill chunks: "
+                  f"{engine.summary['prefill_chunks_by_worker']}")
+        else:
+            engine.run(reqs)
 
     s = engine.summary
     st = engine.pool.stats()
@@ -227,7 +260,8 @@ def main(argv=None):
           f"ttft mean: {s['ttft_mean_s']}s, "
           f"peak prefill staging: {s['peak_prefill_transient_tokens']} "
           f"tokens)")
-    if fault_plan is not None or s["failures"] or s["faults_injected"]:
+    if (args.fault_plan or s["faults_injected"]
+            or any(s[k] for k in RECOVERY_COUNTERS)):
         print(f"[serve] resilience: faults={s['faults_injected']} "
               f"(unfired: {s['faults_unfired']}), "
               f"retries={s['retries']}, "
@@ -238,13 +272,21 @@ def main(argv=None):
               f"deadline_misses={s['deadline_misses']}, "
               f"dead_letters={s['dead_letters']}, "
               f"failures={s['failures']}")
-    return reqs
+    return engine, reqs
+
+
+def main(argv=None):
+    """Serve one CLI invocation in-process; returns the Request list
+    (``r.generated`` holds the token ids).  Raises classified engine
+    errors -- :func:`cli_main` turns them into exit codes."""
+    return serve(argv)[1]
 
 
 def cli_main(argv=None) -> int:
     """Process entry point: classified engine errors become distinct exit
     codes (70-76) plus one structured stderr line instead of a bare
     traceback.  In-process callers use :func:`main`, which raises."""
+    enable_compile_cache()
     try:
         reqs = main(argv)
     except Exception as e:  # noqa: BLE001 -- classified errors only

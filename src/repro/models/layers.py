@@ -193,11 +193,10 @@ def _einsum_qmm(expr, a, b, policy, role, *, out_act=True):
 def _grouped_qmm(a, w, policy, role):
     if not isinstance(w, QTensor):
         return _grouped_xla(a, w, policy, role)
-    # Python-unrolled per expert (loop-free HLO, the repo-wide idiom):
-    # each expert's packed block streams through the fused kernel once
-    outs = [qmatmul(a[e].astype(jnp.float32), w.payload[e], None, w.fmt)
-            for e in range(a.shape[0])]
-    return jnp.stack(outs)
+    # one kernel over all experts (the group axis is a grid axis): each
+    # expert's packed block streams through it once, and a model compiles
+    # one Mosaic program per grouped matmul, not one per expert
+    return qmatmul(a.astype(jnp.float32), w.payload, None, w.fmt)
 
 
 @dispatch.register_matmul("qmm_pallas")

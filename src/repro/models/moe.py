@@ -48,28 +48,43 @@ def moe_apply(p, x, cfg, policy: PrecisionPolicy):
     return _moe_apply_global(p, x, cfg, policy)
 
 
+def route(p, xt, cfg, policy: PrecisionPolicy):
+    """Top-k routing of flattened tokens ``xt`` (T, d) (f32;
+    "router_w"/"router_probs" roles).  Returns (router logits, probs,
+    renormalized top-k weights, top-k expert ids)."""
+    logits = pdot(xt, p["router"], policy, "router_w",
+                  out_act=False).astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, cfg.moe_topk)         # (T, K)
+    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)    # renormalize
+    return logits, probs, act_cast(top_p, policy, "router_probs"), top_e
+
+
+def _aux_loss(probs, top_e, E, K):
+    """Switch-style load-balancing loss: E * sum_e f_e * p_e."""
+    me = jnp.mean(probs, axis=0)
+    ce = jnp.mean(
+        jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32), axis=1), axis=0)
+    return E * jnp.sum(me * ce / K)
+
+
 def _moe_apply_global(p, x, cfg, policy: PrecisionPolicy):
     """Paper-faithful baseline path: global sort-based dispatch, GSPMD left
     to shard it (it cannot -- data-dependent scatter indices force
     replication; kept as the measured baseline in EXPERIMENTS.md Perf)."""
     B, S, d = x.shape
+    xt = x.reshape(B * S, d)
+    _, probs, top_p, top_e = route(p, xt, cfg, policy)
+    aux = _aux_loss(probs, top_e, cfg.moe_experts, cfg.moe_topk)
+    return experts(p, xt, top_p, top_e, cfg, policy).reshape(B, S, d), aux
+
+
+def experts(p, xt, top_p, top_e, cfg, policy: PrecisionPolicy):
+    """The expert FFN of tokens ``xt`` (T, d) under a given routing
+    (:func:`route`): sort-based dispatch with capacity dropping, grouped
+    expert matmuls, weighted combine.  Returns (T, d) activations."""
+    T, d = xt.shape
     E, K = cfg.moe_experts, cfg.moe_topk
-    T = B * S
-    xt = x.reshape(T, d)
-
-    # --- routing (f32; "router_w"/"router_probs" roles) ---------------------
-    logits = pdot(xt, p["router"], policy, "router_w",
-                  out_act=False).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, K)                    # (T, K)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)    # renormalize
-    top_p = act_cast(top_p, policy, "router_probs")
-
-    # aux loss (Switch-style): E * sum_e f_e * p_e
-    me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(
-        jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32), axis=1), axis=0)
-    aux = E * jnp.sum(me * ce / K)
 
     # --- sort-based dispatch -------------------------------------------------
     C = int(np.ceil(cfg.capacity_factor * T * K / E))
@@ -86,7 +101,7 @@ def _moe_apply_global(p, x, cfg, policy: PrecisionPolicy):
     keep = pos < C
     dest = jnp.where(keep, se.astype(jnp.int32) * C + pos, E * C)  # drop slot
 
-    xe = jnp.zeros((E * C + 1, d), x.dtype).at[dest].set(xt[st])
+    xe = jnp.zeros((E * C + 1, d), xt.dtype).at[dest].set(xt[st])
     xe = xe[:E * C].reshape(E, C, d)
 
     # --- grouped expert FFN (active FLOPs only; registry-routed, so with
@@ -104,7 +119,7 @@ def _moe_apply_global(p, x, cfg, policy: PrecisionPolicy):
     gathered = jnp.where(keep[:, None], ye[jnp.where(keep, dest, 0)], 0)
     weighted = gathered.astype(jnp.float32) * sp[:, None].astype(jnp.float32)
     yt = jnp.zeros((T, d), jnp.float32).at[st].add(weighted)
-    return act_cast(yt.reshape(B, S, d), policy), aux
+    return act_cast(yt, policy)
 
 
 # ---------------------------------------------------------------------------
@@ -146,17 +161,8 @@ def moe_apply_sharded(p, x, cfg, policy: PrecisionPolicy, mesh):
         # xb: (B_loc, S, d) tokens of this data shard (replicated over model)
         Tl, dd = T_loc, xb.shape[-1]
         xt = xb.reshape(Tl, dd)
-        logits = pdot(xt, router, policy, "router_w",
-                      out_act=False).astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_p, top_e = jax.lax.top_k(probs, K)
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-        top_p = act_cast(top_p, policy, "router_probs")
-
-        me = jnp.mean(probs, axis=0)
-        ce = jnp.mean(jnp.sum(jax.nn.one_hot(top_e, E, dtype=jnp.float32),
-                              axis=1), axis=0)
-        aux = E * jnp.sum(me * ce / K)
+        _, probs, top_p, top_e = route({"router": router}, xt, cfg, policy)
+        aux = _aux_loss(probs, top_e, E, K)
         aux = jax.lax.pmean(aux, dp) if dp else aux
         aux = jax.lax.pmean(aux, "model")  # identical; makes out_spec P()
 

@@ -8,14 +8,14 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def run_child(code, marker, timeout):
+def run_child(code, marker, timeout, env=None):
     """Run ``code`` in a fresh interpreter and require ``marker`` on stdout.
 
     Failure dumps the child's full stdout/stderr -- a bare exit-status assert
     swallows the child traceback and makes regressions undiagnosable (the
     JAX-0.4.37 API-drift failures hid behind exactly that; CHANGES.md PR 1).
     """
-    env = dict(os.environ)
+    env = {**os.environ, **(env or {})}
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=timeout, env=env)

@@ -360,18 +360,23 @@ _, cache0 = att.prefill_to_cache(p, x, cfg, pol, capacity=64)
 assert cache0.capacity == cfg.window
 wrapped = [i for i in IMPLS if len(dispatch.canonicalize_impl(i)) > 1]
 caches = {impl: cache0 for impl in ["xla"] + wrapped}
+cfgs = {impl: dataclasses.replace(cfg, decode_impl=impl)
+        for impl in ["xla"] + wrapped}
+# one program steps every spelling (op-by-op shard_map dispatch would take
+# minutes): they all project the same x @ wk, which XLA computes once, so
+# every ring must receive the same K bits
+step_all = jax.jit(lambda x, cs: {
+    impl: att.mha(p, x, c, pol, cache=cs[impl]) for impl, c in cfgs.items()})
 with compat.use_mesh(mesh):
     for step in range(12):  # 12 steps > window: wraps the ring
         xt = jax.random.normal(jax.random.PRNGKey(10 + step), (2, 1, 64),
                                jnp.float32) * 0.5
-        o_x, caches["xla"] = att.mha(p, xt, cfg, pol, cache=caches["xla"])
+        outs = step_all(xt, caches)
+        caches = {impl: c for impl, (_, c) in outs.items()}
         for impl in wrapped:
-            cfg_i = dataclasses.replace(cfg, decode_impl=impl)
-            o_i, caches[impl] = att.mha(p, xt, cfg_i, pol,
-                                        cache=caches[impl])
             np.testing.assert_allclose(
-                np.asarray(o_x), np.asarray(o_i), rtol=1e-5, atol=1e-6,
-                err_msg=f"{impl} ring-wrap step {step}")
+                np.asarray(outs["xla"][0]), np.asarray(outs[impl][0]),
+                rtol=1e-5, atol=1e-6, err_msg=f"{impl} ring-wrap step {step}")
             np.testing.assert_array_equal(np.asarray(caches["xla"].k),
                                           np.asarray(caches[impl].k))
 print("CONFORMANCE_2DEV_OK")

@@ -182,8 +182,16 @@ def test_prefill_from_cache_matches_full_prefill(impl):
     np.testing.assert_allclose(np.asarray(full[:, 20:]), np.asarray(out2),
                                rtol=1e-5, atol=1e-6)
     assert int(cache.pos) == 32
-    np.testing.assert_array_equal(np.asarray(cache.k[:, :32]),
-                                  np.asarray(cache_full.k[:, :32]))
+    # K is recomputed per chunk: x @ wk over d_model f32 terms, whose
+    # summation order the compiler may pick per row count (so bits can
+    # differ between a 12- and a 32-row matmul).  Contract bound: the
+    # d-term dot error gamma_d * (|x| @ |wk|), doubled by the rope
+    # rotation (|cos| + |sin| <= sqrt 2), plus rope's own few ulp of |k|.
+    u = 2.0 ** -24
+    dot_err = cfg.d_model * u * float(jnp.max(jnp.abs(x) @ jnp.abs(p["wk"])))
+    kf = np.asarray(cache_full.k[:, :32])
+    bound = 2 * dot_err + 4 * u * float(np.abs(kf).max())
+    assert np.abs(np.asarray(cache.k[:, :32]) - kf).max() <= bound
 
 
 @pytest.mark.parametrize("composed", ["flash_shmap+flash_pallas",

@@ -170,6 +170,17 @@ def test_disaggregate_rejects_wrapper_spellings():
     assert "disaggregate" in str(ei.value)
 
 
+def test_wrapped_spelling_without_usable_mesh_raises():
+    """One device cannot shard: the entry point refuses a wrapped spelling
+    instead of letting the wrapper serve it unsharded."""
+    from repro.launch.serve import main
+    for impl in ("flash_shmap+paged", "ring+paged", "flash_shmap"):
+        with pytest.raises(ValueError, match="at least 2 devices"):
+            main(["--arch", "llama3-8b", "--reduced", "--requests", "1",
+                  "--max-new", "2", "--prompt-len", "4", "--capacity", "16",
+                  "--page-size", "8", "--decode-impl", impl])
+
+
 # ------------------------------------------------------------ CLI builder
 def test_add_backend_args_validates_from_registry():
     from repro.kernels import dispatch

@@ -4,8 +4,8 @@ The cross-backend oracle pins (every registry spelling vs the XLA
 dequantize reference, all formats, ragged lengths, ring-buffer wrap,
 1-/2-device meshes) live in ``tests/test_conformance.py``, parametrized
 from ``dispatch.legal_impls()``.  This file keeps only what is specific
-to the flash kernels themselves: bit-exactness when one KV tile covers
-the cache (identical op sequence), masking of garbage beyond the valid
+to the flash kernels themselves: agreement to the f32 summation-order
+bound when one KV tile covers the cache, masking of garbage beyond the valid
 length, length clamping past capacity, zero-length rows, prefill mask
 variants and gradients, and the model/serve-level wiring.
 """
@@ -18,7 +18,7 @@ import pytest
 
 from repro.core.formats import FpFormat, PAPER_FORMATS
 from repro.core.policy import binary32_policy, transprecision_policy
-from repro.core.qtensor import encode
+from repro.core.qtensor import decode, encode
 from repro.kernels import flash_attention as fa
 from repro.models import attention as att
 from repro.models.base import ModelConfig
@@ -41,14 +41,6 @@ def _pack(k, v, fmt):
     return encode(k, fmt), encode(v, fmt)
 
 
-def _ulp_diff(a, b):
-    """Max distance in representable-f32 steps (lexicographic bit order)."""
-    def lex(x):
-        i = np.asarray(x, np.float32).view(np.int32).astype(np.int64)
-        return np.where(i < 0, np.int64(-(2 ** 31)) - i, i)
-    return int(np.max(np.abs(lex(a) - lex(b))))
-
-
 # ---------------------------------------------------------------- decode
 # (the registry-level ragged oracle pins live in tests/test_conformance.py
 # for EVERY spelling; what stays here is kernel-level behavior the sweep
@@ -68,15 +60,41 @@ def test_flash_decode_multi_tile_matches_dequantize_oracle(fmt):
                                rtol=2e-6, atol=2e-6)
 
 
+def _f32_contract_bound(q, k, v, n_valid):
+    """Largest |kernel - oracle| the compute contract allows when both
+    accumulate in f32 but may sum in different orders (XLA picks the
+    order per backend and per fusion, so a bit-exact pin holds on one
+    compiler build only).  Standard forward bounds with u = 2^-24:
+
+    * a score is a dh-term dot, so its error is at most
+      e_s = dh * u * scale * max sum|q k|;
+    * exp turns that into a relative weight error of at most 2 e_s + u
+      (the running max shifts both sides identically);
+    * the normalized PV is a convex combination of at most ``n_valid``
+      rows, each term and the normalizing sum adding about n_valid * u.
+
+    Hence |delta| <= (4 e_s + (2 n_valid + 4) u) * max|v|.
+    """
+    u = 2.0 ** -24
+    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
+    dh = q.shape[-1]
+    qk = np.einsum("bhgd,bshd->bhgs", np.abs(q), np.abs(k)).max()
+    e_s = dh * u * qk / np.sqrt(dh)
+    return (4 * e_s + (2 * n_valid + 4) * u) * np.abs(v).max()
+
+
 @pytest.mark.parametrize("fmt", FMTS, ids=FMT_IDS)
 def test_flash_decode_single_tile_bit_exact(fmt):
-    """One KV tile covering the cache == the oracle's exact op sequence."""
+    """One KV tile covering the cache == the oracle's op sequence, up to
+    the f32 summation order the compiler is free to choose."""
     q, k, v = _mk(S=96)
     kp, vp = _pack(k, v, fmt)
     lengths = jnp.asarray([96, 5, 64], jnp.int32)
     got = fa.flash_decode(q, kp, vp, fmt, lengths, block_kv=128)
     want = fa.flash_decode_reference(q, kp, vp, fmt, lengths)
-    assert _ulp_diff(got, want) <= 1
+    kd, vd = (x if fmt is None else decode(x, fmt) for x in (kp, vp))
+    bound = _f32_contract_bound(q, kd, vd, n_valid=96)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() <= bound
 
 
 def test_flash_decode_ignores_invalid_slots():

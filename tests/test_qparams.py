@@ -318,7 +318,7 @@ with mesh:
     compiled = jax.jit(
         lambda p, t, s: model.decode_step(p, t, s, policy),
         donate_argnums=(2,)).lower(params, tokens, states).compile()
-    assert compat.cost_analysis(compiled).get("flops", 0) > 0
+    assert compiled.cost_analysis().get("flops", 0) > 0
     print("QWEIGHTS_CELL_OK")
 """
     run_child(code, "QWEIGHTS_CELL_OK", timeout=420)

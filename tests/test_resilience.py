@@ -451,8 +451,11 @@ def test_midstream_abort_then_readmission_same_rid(served_model,
 
 
 # ------------------------------------------- serve CLI exit codes (sat 2)
-def test_serve_cli_maps_classified_errors_to_exit_codes(capsys):
+def test_serve_cli_maps_classified_errors_to_exit_codes(capsys, monkeypatch,
+                                                       tmp_path):
     from repro.launch.serve import cli_main
+    # cli_main enables the persistent compile cache; keep it out of the repo
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     base = ["--arch", "llama3-8b", "--reduced", "--requests", "2",
             "--slots", "1", "--prompt-len", "8", "--max-new", "2",
             "--capacity", "32", "--decode-impl", "paged"]
@@ -470,3 +473,32 @@ def test_serve_cli_maps_classified_errors_to_exit_codes(capsys):
     code = cli_main(base + ["--max-new", "8", "--watchdog-s", "0.0"])
     assert code == WatchdogTimeout.exit_code == 75
     assert "[serve:error] kind=watchdog exit=75" in capsys.readouterr().err
+
+
+def test_serve_prints_resilience_line_for_unplanned_quarantine(
+        capsys, monkeypatch):
+    """A NaN-guard trip with no fault plan (a kernel returning NaN on the
+    device, say) is recovered by the oracle replay -- the run still
+    succeeds, so the recovery must be visible in the output."""
+    import jax.numpy as jnp
+
+    from repro.engine.scheduler import Engine
+    from repro.launch.serve import main
+
+    calls = []
+    real = Engine._fault_mask
+
+    def poison_first_decode(self, kind, decoding):
+        if kind == "nan_logits" and not calls:
+            calls.append(kind)
+            return jnp.ones((self.slots,), jnp.bool_)
+        return real(self, kind, decoding)
+
+    monkeypatch.setattr(Engine, "_fault_mask", poison_first_decode)
+    reqs = main(["--arch", "llama3-8b", "--reduced", "--requests", "1",
+                 "--slots", "1", "--prompt-len", "8", "--max-new", "3",
+                 "--capacity", "32", "--decode-impl", "paged"])
+    assert all(r.done and r.error is None for r in reqs)
+    out = capsys.readouterr().out
+    assert "[serve] resilience:" in out
+    assert "quarantines=1" in out

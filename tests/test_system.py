@@ -93,7 +93,7 @@ with mesh:
         return loss, adamw.materialize_params(no, p, policy), no
 
     compiled = jax.jit(step).lower(params, opt, batch).compile()
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     assert cost["flops"] > 0
     print("SMALL_MESH_OK", cost["flops"])
 """
@@ -231,6 +231,14 @@ with compat.use_mesh(mesh):
         toks = [r.generated for r in got]
         assert all(r.done for r in got), impl
         assert toks == want, ("greedy divergence", impl, toks, want)
+        # 7 pool pages cannot split over 2 devices: the entry point
+        # refuses instead of serving the pool unsharded
+        try:
+            main(args + ["--decode-impl", impl, "--pool-pages", "7"])
+        except ValueError as e:
+            assert "must both divide" in str(e), e
+        else:
+            raise AssertionError(f"{impl}: indivisible pool was served")
 print("SERVE_REGISTRY_2DEV_OK")
 """
 
