@@ -85,7 +85,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.kernels import paged_cache
 
-from . import resilience
+from . import resilience, tracing
 from .faults import FaultInjector, FaultPlan, SimulatedFault
 from .reference import synchronous_generate
 from .stats import EngineStats
@@ -103,8 +103,10 @@ def _host(tree):
     sequence (the old ``int(nxt[si])`` loop pulled the whole logits row
     once per slot).  Tests monkeypatch this to count transfers and run the
     loop under ``jax.transfer_guard_device_to_host("disallow")`` to prove
-    no implicit transfer remains."""
-    return jax.device_get(tree)
+    no implicit transfer remains.  Its span is the only one in which the
+    host waits for the chip."""
+    with tracing.span(tracing.DEVICE_WAIT):
+        return jax.device_get(tree)
 
 
 class Request:
@@ -328,7 +330,6 @@ class Engine:
         self._tokens = jnp.zeros((slots, 1), jnp.int32)
         self._terminal = 0        # requests that reached a terminal state
         self._step_done: List[Request] = []  # terminal this step
-        self.decode_steps = 0
         self._engine_step = 0
         self._progressed = False  # non-step progress (failures) this step
         self._new_tokens = 0
@@ -358,21 +359,22 @@ class Engine:
         """Mirror the host block tables onto the device; ``mask_slots``
         hides the mid-prefill slots from the decode step (-1 rows drop
         ``append_decode`` writes and keep their lengths frozen)."""
-        tables = self.pool.tables
-        if mask_slots:
-            tables = tables.copy()
-            for si in mask_slots:
-                tables[si] = -1
-        for li in self.attn_layers:
-            self.states[li] = paged_cache.set_block_tables(self.states[li],
-                                                           tables)
-        if self.spec is not None:
-            dtables = self.pool.ns_tables(self.spec.NS)
+        with tracing.span(tracing.PUSH_TABLES):
+            tables = self.pool.tables
             if mask_slots:
-                dtables = dtables.copy()
+                tables = tables.copy()
                 for si in mask_slots:
-                    dtables[si] = -1
-            self.spec.push_tables(dtables)
+                    tables[si] = -1
+            for li in self.attn_layers:
+                self.states[li] = paged_cache.set_block_tables(
+                    self.states[li], tables)
+            if self.spec is not None:
+                dtables = self.pool.ns_tables(self.spec.NS)
+                if mask_slots:
+                    dtables = dtables.copy()
+                    for si in mask_slots:
+                        dtables[si] = -1
+                self.spec.push_tables(dtables)
 
     def _init_pstates(self, transport):
         """B=1 recurrent-layer states for a fresh prompt (attn -> None:
@@ -455,16 +457,18 @@ class Engine:
     def _release_slot_state(self, si: int) -> None:
         """Free ``si`` everywhere: pool pages (all namespaces), device
         table rows, draft rows, and any in-flight prefill."""
-        self.pool.free_slot(si)  # frees BOTH namespaces atomically
-        for li in self.attn_layers:
-            self.states[li] = paged_cache.release_slot(self.states[li], si)
-        if self.spec is not None:
-            self.spec.release_slot(si)
-        task = self._task_for_slot(si)
-        if task is not None:
-            self.transports[task.worker].abort(self, task)
-            self._tasks.remove(task)
-        self._slots[si] = None
+        with tracing.span(tracing.RELEASE, rid=self._slots[si].rid):
+            self.pool.free_slot(si)  # frees BOTH namespaces atomically
+            for li in self.attn_layers:
+                self.states[li] = paged_cache.release_slot(self.states[li],
+                                                           si)
+            if self.spec is not None:
+                self.spec.release_slot(si)
+            task = self._task_for_slot(si)
+            if task is not None:
+                self.transports[task.worker].abort(self, task)
+                self._tasks.remove(task)
+            self._slots[si] = None
 
     def _evict(self, si: int) -> None:
         # an eviction IS step progress: the requeued request becomes
@@ -505,12 +509,14 @@ class Engine:
         engine's tokens are pinned bit-identical to, so recovery
         preserves the determinism contract.  -> tokens emitted now."""
         r = self._slots[si]
-        pages = self.pool.quarantine_slot(si)
-        for li in self.attn_layers:
-            self.states[li] = paged_cache.release_slot(self.states[li], si)
-        if self.spec is not None:
-            self.spec.release_slot(si)
-        self._slots[si] = None
+        with tracing.span(tracing.RELEASE, rid=r.rid):
+            pages = self.pool.quarantine_slot(si)
+            for li in self.attn_layers:
+                self.states[li] = paged_cache.release_slot(self.states[li],
+                                                           si)
+            if self.spec is not None:
+                self.spec.release_slot(si)
+            self._slots[si] = None
         self.stats.note_quarantine(pages)
         prev = len(r.generated)
         out = synchronous_generate(
@@ -532,35 +538,41 @@ class Engine:
         states, read its first token (one host transfer), and hand the
         slot to the decode batch."""
         r, si = task.request, task.slot
-        tr = self.transports[task.worker]
-        for li, kind in enumerate(self.cfg.attn_pattern):
-            if kind != "attn":
-                self.states[li] = _insert_slot(
-                    self.states[li], tr.to_decode(task.pstates[li]),
-                    si, self.slots)
-        am, fin = _host((jnp.argmax(task.logits[0, -1]),
-                         jnp.isfinite(task.logits[0, -1]).all()))
-        if not bool(fin):
-            self._new_tokens += self._quarantine_and_replay(
-                si, "prefill logits")
-            return
-        nxt = int(am)
-        r.generated.append(nxt)
-        self.stats.note_first_token(r.rid)
-        self.stats.note_decode_tokens(1)
-        self._new_tokens += 1
-        self._tokens = self._tokens.at[si, 0].set(nxt)
-        if self.spec is not None:
-            # the target prompt just landed; write the draft's KV for it
-            # into the draft-namespace pages (tables were pushed at the
-            # top of the prefill section)
-            self.spec.prefill_prompt(si, r.prompt)
+        with tracing.span(tracing.FIRST_TOKEN, rid=r.rid):
+            tr = self.transports[task.worker]
+            for li, kind in enumerate(self.cfg.attn_pattern):
+                if kind != "attn":
+                    self.states[li] = _insert_slot(
+                        self.states[li], tr.to_decode(task.pstates[li]),
+                        si, self.slots)
+            am, fin = _host((jnp.argmax(task.logits[0, -1]),
+                             jnp.isfinite(task.logits[0, -1]).all()))
+            if not bool(fin):
+                self._new_tokens += self._quarantine_and_replay(
+                    si, "prefill logits")
+                return
+            nxt = int(am)
+            r.generated.append(nxt)
+            self.stats.note_first_token(r.rid)
+            self.stats.note_decode_tokens(1)
+            self._new_tokens += 1
+            self._tokens = self._tokens.at[si, 0].set(nxt)
+            if self.spec is not None:
+                # the target prompt just landed; write the draft's KV for
+                # it into the draft-namespace pages (tables were pushed at
+                # the top of the prefill section)
+                self.spec.prefill_prompt(si, r.prompt)
 
     # -------------------------------------------------------------------- step
     def step(self) -> List[Request]:
         """One engine iteration over the current queue/slots; returns the
         requests that reached a terminal state (done or classified
-        failure) during this step."""
+        failure) during this step.  The whole iteration is the root span
+        (:mod:`~repro.engine.tracing`)."""
+        with tracing.step_span(tracing.STEP, step_num=self._engine_step + 1):
+            return self._step()
+
+    def _step(self) -> List[Request]:
         n = self.slots
         self._step_done = []
         step = self._engine_step + 1    # 1-based, matches stats records
@@ -585,35 +597,38 @@ class Engine:
                     f"request {r.rid} exceeded its {dl}-step deadline "
                     f"({len(r.generated)}/{r.max_new} tokens)"))
         # ---- admission: one prompt in flight per idle prefill worker ----
-        while self._queue and len(self._tasks) < self.n_prefill_workers:
-            si = next((i for i in range(n) if self._slots[i] is None), None)
-            if si is None:
-                break
-            need = len(self._queue[0].prompt)
-            needs = ((need + 1, need) if self.spec is not None
-                     else (need + 1,))
-            if not self.pool.can_admit(*needs):
-                break
-            r = self._queue.pop(0)
-            ok = self.pool.allocate(si, need)
-            if self.spec is not None:
-                ok = ok and self.pool.allocate(si, need, ns=self.spec.NS)
-            assert ok, (si, need)  # can_admit held above
-            self._slots[si] = r
-            self._admissions += 1
-            self._admitted_at[si] = self._admissions
-            self.stats.note_admitted(r.rid)
-            if self.calibration_tap is not None:
-                # live-traffic tap: admitted prompts feed the serve-
-                # time precision tuner's calibration reservoir
-                self.calibration_tap.observe(r.prompt)
-            busy = {t.worker for t in self._tasks}
-            wi = next(w for w in range(self.n_prefill_workers)
-                      if w not in busy)
-            task = PrefillTask(r, si, need, worker=wi)
-            task.pstates = self._init_pstates(self.transports[wi])
-            self.transports[wi].begin(self, task)
-            self._tasks.append(task)
+        with tracing.span(tracing.ADMIT):
+            while self._queue and len(self._tasks) < self.n_prefill_workers:
+                si = next((i for i in range(n) if self._slots[i] is None),
+                          None)
+                if si is None:
+                    break
+                need = len(self._queue[0].prompt)
+                needs = ((need + 1, need) if self.spec is not None
+                         else (need + 1,))
+                if not self.pool.can_admit(*needs):
+                    break
+                r = self._queue.pop(0)
+                ok = self.pool.allocate(si, need)
+                if self.spec is not None:
+                    ok = ok and self.pool.allocate(si, need,
+                                                   ns=self.spec.NS)
+                assert ok, (si, need)  # can_admit held above
+                self._slots[si] = r
+                self._admissions += 1
+                self._admitted_at[si] = self._admissions
+                self.stats.note_admitted(r.rid)
+                if self.calibration_tap is not None:
+                    # live-traffic tap: admitted prompts feed the serve-
+                    # time precision tuner's calibration reservoir
+                    self.calibration_tap.observe(r.prompt)
+                busy = {t.worker for t in self._tasks}
+                wi = next(w for w in range(self.n_prefill_workers)
+                          if w not in busy)
+                task = PrefillTask(r, si, need, worker=wi)
+                task.pstates = self._init_pstates(self.transports[wi])
+                self.transports[wi].begin(self, task)
+                self._tasks.append(task)
         # ---- one prefill chunk per task (decode below still runs) -------
         ran_chunks = 0
         if self._tasks:
@@ -622,58 +637,64 @@ class Engine:
                 ran_chunks += 1
                 self.stats.note_prefill_chunk(task.worker)
                 tr = self.transports[task.worker]
-                try:
-                    view, vslot = tr.prefill_view(self, task)
-                    view = self.prefill_workers[task.worker].step(
-                        task, view, vslot)
-                    tr.absorb(self, task, view)
+                worker = self.prefill_workers[task.worker]
+                with tracing.span(tracing.PREFILL, rid=task.request.rid,
+                                  tokens=worker.next_tokens(task)):
+                    try:
+                        view, vslot = tr.prefill_view(self, task)
+                        view = worker.step(task, view, vslot)
+                        with tracing.span(tracing.ABSORB):
+                            tr.absorb(self, task, view)
+                        if task.done:
+                            with tracing.span(tracing.FINISH):
+                                tr.finish(self, task)
+                    except resilience.TransportError:
+                        # checksum refetch exhausted: the page handoff
+                        # cannot be trusted, so recompute the request from
+                        # its prompt (bounded by max_requeues like any
+                        # other eviction)
+                        self._evict(task.slot)
+                        continue
                     if task.done:
-                        tr.finish(self, task)
-                except resilience.TransportError:
-                    # checksum refetch exhausted: the page handoff cannot
-                    # be trusted, so recompute the request from its prompt
-                    # (bounded by max_requeues like any other eviction)
-                    self._evict(task.slot)
-                    continue
-                if task.done:
-                    self._tasks.remove(task)
-                    self._complete_prefill(task)
+                        self._tasks.remove(task)
+                        self._complete_prefill(task)
         # ---- growth: every decoding slot needs a mapped page for its
         # next token; evict LIFO when the pool runs dry ------------------
         use_spec = (self.spec is not None
                     and self.breaker.allows(step))
         task_slots = {t.slot for t in self._tasks}
-        for si in range(n):
-            if self._slots[si] is None or si in task_slots:
-                continue
-            while self._slots[si] is not None:
-                L = int(self.pool.lens[si])
-                if use_spec:
-                    # grow by this round's worst case in BOTH
-                    # namespaces: k appends, clamped to what the
-                    # request can still emit
-                    gi = min(self.spec.k, self._slots[si].max_new
-                             - len(self._slots[si].generated))
-                    ok = (self.pool.ensure_capacity(si, L + gi)
-                          and self.pool.ensure_capacity(
-                              si, L + gi, ns=self.spec.NS))
-                elif self.spec is not None:
-                    # degraded (breaker-open) step: one token, but the
-                    # draft shadow append needs its page too
-                    ok = (self.pool.ensure_capacity(si, L + 1)
-                          and self.pool.ensure_capacity(
-                              si, L + 1, ns=self.spec.NS))
-                else:
-                    ok = self.pool.ensure_capacity(si, L + 1)
-                if ok and self.injector.pool_exhausted():
-                    ok = False  # injected exhaustion: walk the normal
-                if ok:          # eviction/requeue path below
-                    break
-                victim = self._newest_active()
-                self._evict(victim)
-                task_slots = {t.slot for t in self._tasks}
-                if victim == si:
-                    break
+        with tracing.span(tracing.GROW):
+            for si in range(n):
+                if self._slots[si] is None or si in task_slots:
+                    continue
+                while self._slots[si] is not None:
+                    L = int(self.pool.lens[si])
+                    if use_spec:
+                        # grow by this round's worst case in BOTH
+                        # namespaces: k appends, clamped to what the
+                        # request can still emit
+                        gi = min(self.spec.k, self._slots[si].max_new
+                                 - len(self._slots[si].generated))
+                        ok = (self.pool.ensure_capacity(si, L + gi)
+                              and self.pool.ensure_capacity(
+                                  si, L + gi, ns=self.spec.NS))
+                    elif self.spec is not None:
+                        # degraded (breaker-open) step: one token, but the
+                        # draft shadow append needs its page too
+                        ok = (self.pool.ensure_capacity(si, L + 1)
+                              and self.pool.ensure_capacity(
+                                  si, L + 1, ns=self.spec.NS))
+                    else:
+                        ok = self.pool.ensure_capacity(si, L + 1)
+                    if ok and self.injector.pool_exhausted():
+                        ok = False  # injected exhaustion: walk the normal
+                    if ok:          # eviction/requeue path below
+                        break
+                    victim = self._newest_active()
+                    self._evict(victim)
+                    task_slots = {t.slot for t in self._tasks}
+                    if victim == si:
+                        break
         # ---- one batched decode step over the page pool ---------------
         decoding = [si for si in range(n)
                     if self._slots[si] is not None and si not in task_slots]
@@ -685,57 +706,58 @@ class Engine:
 
             def _spec_call():
                 self.injector.maybe_raise()
-                return self.spec.round(self.params, self._tokens,
-                                       self.states, nan_mask=nan_mask,
-                                       div_mask=div_mask)
+                with tracing.span(tracing.SPEC_ROUND):
+                    return self.spec.round(
+                        self.params, self._tokens, self.states,
+                        nan_mask=nan_mask, div_mask=div_mask)
 
             (tgt_d, m_d, acc_d, pending, bad_d,
              self.states) = resilience.with_retries(
                 _spec_call, self.retry_policy, self.stats,
                 retriable=(SimulatedFault,), what="speculation round")
-            self.decode_steps += 1
             self.stats.note_target_step()
             tgt, m, acc, bad = _host((tgt_d, m_d, acc_d, bad_d))
             proposed = accepted = 0
-            for si in decoding:
-                if bool(bad[si]):
-                    self._new_tokens += self._quarantine_and_replay(
-                        si, "verify logits")
-                    continue
-                r = self._slots[si]
-                L = int(self.pool.lens[si])
-                gi = min(self.spec.k, r.max_new - len(r.generated))
-                # positions >= gi had no mapped page (growth clamped
-                # to gi); the device rollback took the same min, so
-                # clamp the host-side view identically
-                mi = min(int(m[si]), gi)
-                r.generated.extend(int(t) for t in tgt[si, :mi])
-                self.stats.note_decode_tokens(mi)
-                self._new_tokens += mi
-                proposed += gi
-                accepted += min(int(acc[si]), gi)
-                self.pool.truncate(si, L + mi)
-                self.pool.truncate(si, L + mi, ns=self.spec.NS)
-                if len(r.generated) >= r.max_new:
-                    self._finish_slot(si)
-            self.stats.note_spec_round(proposed=proposed,
-                                       accepted=accepted)
-            self.breaker.record(step=step, proposed=proposed,
-                                accepted=accepted, stats=self.stats)
-            self._tokens = pending
+            with tracing.span(tracing.EMIT):
+                for si in decoding:
+                    if bool(bad[si]):
+                        self._new_tokens += self._quarantine_and_replay(
+                            si, "verify logits")
+                        continue
+                    r = self._slots[si]
+                    L = int(self.pool.lens[si])
+                    gi = min(self.spec.k, r.max_new - len(r.generated))
+                    # positions >= gi had no mapped page (growth clamped
+                    # to gi); the device rollback took the same min, so
+                    # clamp the host-side view identically
+                    mi = min(int(m[si]), gi)
+                    r.generated.extend(int(t) for t in tgt[si, :mi])
+                    self.stats.note_decode_tokens(mi)
+                    self._new_tokens += mi
+                    proposed += gi
+                    accepted += min(int(acc[si]), gi)
+                    self.pool.truncate(si, L + mi)
+                    self.pool.truncate(si, L + mi, ns=self.spec.NS)
+                    if len(r.generated) >= r.max_new:
+                        self._finish_slot(si)
+                self.stats.note_spec_round(proposed=proposed,
+                                           accepted=accepted)
+                self.breaker.record(step=step, proposed=proposed,
+                                    accepted=accepted, stats=self.stats)
+                self._tokens = pending
         elif decoding:
             self._push_tables(mask_slots=task_slots)
             nan_mask = self._fault_mask("nan_logits", decoding)
 
             def _decode_call():
                 self.injector.maybe_raise()
-                return self.decode_worker.step(self.params, self._tokens,
-                                               self.states, nan_mask)
+                with tracing.span(tracing.DECODE_DISPATCH):
+                    return self.decode_worker.step(
+                        self.params, self._tokens, self.states, nan_mask)
 
             nxt, bad_d, self.states = resilience.with_retries(
                 _decode_call, self.retry_policy, self.stats,
                 retriable=(SimulatedFault,), what="decode step")
-            self.decode_steps += 1
             self.stats.note_target_step()
             if self.spec is not None:
                 # breaker open: plain decode, but keep the draft KV in
@@ -743,21 +765,22 @@ class Engine:
                 self.spec.shadow_step(self._tokens)
                 self.stats.note_degraded_step()
             nxt_h, bad = _host((nxt, bad_d))
-            for si in decoding:
-                if bool(bad[si]):
-                    self._new_tokens += self._quarantine_and_replay(
-                        si, "decode logits")
-                    continue
-                r = self._slots[si]
-                self.pool.note_decode_step(si)
-                if self.spec is not None:
-                    self.pool.note_decode_step(si, ns=self.spec.NS)
-                r.generated.append(int(nxt_h[si]))
-                self.stats.note_decode_tokens(1)
-                self._new_tokens += 1
-                if len(r.generated) >= r.max_new:
-                    self._finish_slot(si)
-            self._tokens = nxt[:, None]
+            with tracing.span(tracing.EMIT):
+                for si in decoding:
+                    if bool(bad[si]):
+                        self._new_tokens += self._quarantine_and_replay(
+                            si, "decode logits")
+                        continue
+                    r = self._slots[si]
+                    self.pool.note_decode_step(si)
+                    if self.spec is not None:
+                        self.pool.note_decode_step(si, ns=self.spec.NS)
+                    r.generated.append(int(nxt_h[si]))
+                    self.stats.note_decode_tokens(1)
+                    self._new_tokens += 1
+                    if len(r.generated) >= r.max_new:
+                        self._finish_slot(si)
+                self._tokens = nxt[:, None]
         elif self.has_work() and not ran_chunks and not self._progressed:
             # pre-run feasibility makes this unreachable without page
             # quarantine; with it, a loud classified error beats a hang
@@ -766,10 +789,11 @@ class Engine:
                 "admissible and no sequence decoding (quarantined "
                 f"pages: {len(self.pool.quarantined)})")
         self._engine_step += 1
-        self.stats.step_record(
-            step=self._engine_step, queue_depth=len(self._queue),
-            prefilling=ran_chunks, decoding=len(decoding),
-            new_tokens=self._new_tokens, pool_stats=self.pool.stats())
+        with tracing.span(tracing.RECORD):
+            self.stats.step_record(
+                step=self._engine_step, queue_depth=len(self._queue),
+                prefilling=ran_chunks, decoding=len(decoding),
+                new_tokens=self._new_tokens, pool_stats=self.pool.stats())
         if self.watchdog_s is not None:
             if time.perf_counter() - t_step > self.watchdog_s:
                 self.stats.note_watchdog_trip()

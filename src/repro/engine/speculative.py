@@ -45,6 +45,8 @@ import jax.numpy as jnp
 
 from repro.kernels import paged_cache
 
+from . import tracing
+
 DRAFT_NAMESPACE = "draft"
 
 
@@ -194,7 +196,8 @@ class SpeculativeDecoder:
         draft KV by the token the target just consumed (the scheduler
         decodes plain): the draft cache stays in lockstep with the target,
         so acceptance has a chance the moment the breaker re-probes."""
-        self.states = self._shadow(self.params, tokens, self.states)
+        with tracing.span(tracing.SPEC_SHADOW):
+            self.states = self._shadow(self.params, tokens, self.states)
 
     def round(self, params, tokens, states, nan_mask=None, div_mask=None):
         """One speculation round.  Returns device-side

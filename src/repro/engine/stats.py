@@ -168,7 +168,6 @@ class EngineStats:
         rec = {
             "kind": "step",
             "step": step,
-            "t_s": round(time.perf_counter() - self._t0, 6),
             "queue_depth": queue_depth,
             "prefilling": prefilling,
             "decoding": decoding,

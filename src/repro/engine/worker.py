@@ -20,6 +20,8 @@ import jax.numpy as jnp
 
 from repro.kernels import paged_cache
 
+from . import tracing
+
 
 class PrefillTask:
     """One in-flight prompt: chunk cursor, stream cursor, and result."""
@@ -73,17 +75,25 @@ class PrefillWorker:
         # prompt-sized, immediately rewritten into pages
         self._whole = jax.jit(lambda p, b: model.prefill(p, b, policy, None))
 
+    def next_tokens(self, task: PrefillTask) -> int:
+        """Prompt tokens the next :meth:`step` of ``task`` prefills."""
+        left = task.n_tokens - task.offset
+        return min(self.chunk_tokens, left) if self.chunked else left
+
     def step(self, task: PrefillTask, view_states, slot: int):
         """Advance ``task`` by one chunk (or the whole prompt); returns
         the updated state view for the transport to absorb."""
         if not self.chunked:
             return self._whole_step(task, view_states, slot)
-        C = min(self.chunk_tokens, task.n_tokens - task.offset)
-        toks = task.request.prompt[task.offset:task.offset + C]
-        t = self.transport.to_prefill(jnp.asarray([toks], jnp.int32))
-        logits, view_states, task.pstates = self._chunk(
-            self.transport.params, t, view_states, task.pstates,
-            slot, task.offset)
+        C = self.next_tokens(task)
+        with tracing.span(tracing.PREFILL_DISPATCH):
+            toks = task.request.prompt[task.offset:task.offset + C]
+            t = self.transport.to_prefill(jnp.asarray([toks], jnp.int32))
+            # the chunk's K/V land in the pool inside this program
+            # (paged_cache.write_chunk): no eager pool write follows
+            logits, view_states, task.pstates = self._chunk(
+                self.transport.params, t, view_states, task.pstates,
+                slot, task.offset)
         self.stats.note_prefill_transient(C)
         task.offset += C
         if task.offset >= task.n_tokens:
@@ -92,15 +102,18 @@ class PrefillWorker:
         return view_states
 
     def _whole_step(self, task: PrefillTask, view_states, slot: int):
-        batch = self.transport.to_prefill(make_batch(self.cfg, task.request))
-        logits, one_states = self._whole(self.transport.params, batch)
-        for li, kind in enumerate(self.cfg.attn_pattern):
-            if kind == "attn":
-                view_states[li] = paged_cache.write_prefill(
-                    view_states[li], slot,
-                    one_states[li].k[0], one_states[li].v[0])
-            else:
-                task.pstates[li] = one_states[li]
+        with tracing.span(tracing.PREFILL_DISPATCH):
+            batch = self.transport.to_prefill(
+                make_batch(self.cfg, task.request))
+            logits, one_states = self._whole(self.transport.params, batch)
+        with tracing.span(tracing.POOL_WRITE):
+            for li, kind in enumerate(self.cfg.attn_pattern):
+                if kind == "attn":
+                    view_states[li] = paged_cache.write_prefill(
+                        view_states[li], slot,
+                        one_states[li].k[0], one_states[li].v[0])
+                else:
+                    task.pstates[li] = one_states[li]
         self.stats.note_prefill_transient(task.n_tokens)
         task.offset = task.n_tokens
         task.done = True

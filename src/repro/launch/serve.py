@@ -244,7 +244,7 @@ def serve(argv=None, *, n_layers=None):
     kv_desc = kv_fmts[0] if len(kv_fmts) == 1 \
         else "per-layer[" + ",".join(kv_fmts) + "]"
     print(f"[serve] {len(reqs)} requests, {total_tokens} tokens, "
-          f"{engine.decode_steps} batched steps, "
+          f"{engine.stats.target_steps} batched steps, "
           f"{total_tokens/dt:.1f} tok/s "
           f"(kv format: {kv_desc}, "
           f"decode: {effective_impl}, "

@@ -415,12 +415,14 @@ def mha(p, x, cfg, policy: PrecisionPolicy, *,
                 f"pages_per_seq * page_size <= window (every cached token "
                 f"then sits inside the window) or use a contiguous KVCache "
                 f"ring buffer")
-        new_cache = paged_cache.append_decode(cache, k, v)
+        with jax.named_scope("kv_append"):
+            new_cache = paged_cache.append_decode(cache, k, v)
         fn = dispatch.resolve_decode(impl)
         if dispatch.canonicalize_impl(impl)[-1] == "paged":
-            out = fn(qg[:, 0], new_cache.k_pool, new_cache.v_pool,
-                     new_cache.seq_lens, scale=scale, policy=policy,
-                     block_tables=new_cache.block_tables)
+            with jax.named_scope("attn_kernel"):
+                out = fn(qg[:, 0], new_cache.k_pool, new_cache.v_pool,
+                         new_cache.seq_lens, scale=scale, policy=policy,
+                         block_tables=new_cache.block_tables)
         else:
             # contiguous-impl bridge (the reverse of
             # paged_view_of_contiguous): gather every slot's pages into the
@@ -429,12 +431,14 @@ def mha(p, x, cfg, policy: PrecisionPolicy, *,
             # pages alias physical page 0 in the gather; their positions sit
             # at or beyond seq_lens, which every decode backend masks -- so
             # ALL registry spellings serve one paged state.
-            ckg = paged_cache.gather_pages(new_cache.k_pool,
-                                           new_cache.block_tables)
-            cvg = paged_cache.gather_pages(new_cache.v_pool,
-                                           new_cache.block_tables)
-            out = fn(qg[:, 0], ckg, cvg, new_cache.seq_lens, scale=scale,
-                     policy=policy)
+            with jax.named_scope("gather_pages"):
+                ckg = paged_cache.gather_pages(new_cache.k_pool,
+                                               new_cache.block_tables)
+                cvg = paged_cache.gather_pages(new_cache.v_pool,
+                                               new_cache.block_tables)
+            with jax.named_scope("attn_kernel"):
+                out = fn(qg[:, 0], ckg, cvg, new_cache.seq_lens,
+                         scale=scale, policy=policy)
         out = act_cast(out, policy)[:, None]
     elif cache is not None:
         # ---- decode: append k/v then attend over the cache ----------------
@@ -486,8 +490,9 @@ def mha(p, x, cfg, policy: PrecisionPolicy, *,
     elif causal and kv_source is None:
         # ---- prefill through the registry ---------------------------------
         fn = dispatch.resolve_prefill(impl)
-        out = fn(qg, k, v, scale=scale, policy=policy, window=cfg.window,
-                 prefix_len=prefix_len, chunk=chunk)
+        with jax.named_scope("attn_kernel"):
+            out = fn(qg, k, v, scale=scale, policy=policy,
+                     window=cfg.window, prefix_len=prefix_len, chunk=chunk)
     else:
         # ---- non-causal full attention (encoder self-attn / cross-attn) ---
         scores = _gqa_scores(qg, k, policy).astype(jnp.float32) * scale
@@ -541,7 +546,8 @@ def verify_paged(p, x, cfg, policy: PrecisionPolicy, cache: PagedKVCache):
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
 
-    new_cache = paged_cache.append_block(cache, k, v)
+    with jax.named_scope("kv_append"):
+        new_cache = paged_cache.append_block(cache, k, v)
 
     scale = np.float32(1.0 / np.sqrt(dh))
     qg = q.reshape(B, K, n_kv, G, dh)
@@ -553,22 +559,25 @@ def verify_paged(p, x, cfg, policy: PrecisionPolicy, cache: PagedKVCache):
         # positions -- entries at or beyond each position's n_valid are
         # masked by the backend, so the post-append view is exact for
         # every position (same reasoning as the mha paged branch)
-        ckg = paged_cache.gather_pages(new_cache.k_pool,
-                                      new_cache.block_tables)
-        cvg = paged_cache.gather_pages(new_cache.v_pool,
-                                      new_cache.block_tables)
+        with jax.named_scope("gather_pages"):
+            ckg = paged_cache.gather_pages(new_cache.k_pool,
+                                          new_cache.block_tables)
+            cvg = paged_cache.gather_pages(new_cache.v_pool,
+                                          new_cache.block_tables)
     outs = []
     for i in range(K):
         # frozen (masked / unmapped) slots advanced 0..i tokens; clamping
         # to the post-append length reproduces the sequential decode
         # step's n_valid exactly for every slot
         n_valid = jnp.minimum(base + (i + 1), new_cache.seq_lens)
-        if paged_base:
-            o = fn(qg[:, i], new_cache.k_pool, new_cache.v_pool, n_valid,
-                   scale=scale, policy=policy,
-                   block_tables=new_cache.block_tables)
-        else:
-            o = fn(qg[:, i], ckg, cvg, n_valid, scale=scale, policy=policy)
+        with jax.named_scope("attn_kernel"):
+            if paged_base:
+                o = fn(qg[:, i], new_cache.k_pool, new_cache.v_pool,
+                       n_valid, scale=scale, policy=policy,
+                       block_tables=new_cache.block_tables)
+            else:
+                o = fn(qg[:, i], ckg, cvg, n_valid, scale=scale,
+                       policy=policy)
         outs.append(act_cast(o, policy))
     out = jnp.stack(outs, axis=1)
     out = out.reshape(B, K, cfg.q_dim)
@@ -710,17 +719,21 @@ def prefill_paged_chunk(p, x, cfg, policy, cache: PagedKVCache, slot: int,
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
 
-    new_cache = paged_cache.write_chunk(cache, slot, k[0], v[0], q_offset)
+    with jax.named_scope("kv_append"):
+        new_cache = paged_cache.write_chunk(cache, slot, k[0], v[0],
+                                            q_offset)
 
     scale = np.float32(1.0 / np.sqrt(dh))
     qg = q.reshape(B, S, n_kv, G, dh)
     impl = decode_impl(cfg, policy)
     fn = dispatch.resolve_prefill(impl)
-    tbl = new_cache.block_tables[slot:slot + 1]
-    ck = paged_cache.gather_pages(new_cache.k_pool, tbl)
-    cv = paged_cache.gather_pages(new_cache.v_pool, tbl)
+    with jax.named_scope("gather_pages"):
+        tbl = new_cache.block_tables[slot:slot + 1]
+        ck = paged_cache.gather_pages(new_cache.k_pool, tbl)
+        cv = paged_cache.gather_pages(new_cache.v_pool, tbl)
     kp, vp, fmt = _cache_payload(ck, cv, policy)
-    out = fn(qg, kp, vp, scale=scale, policy=policy, window=cfg.window,
-             prefix_len=0, chunk=chunk, q_offset=q_offset, fmt=fmt)
+    with jax.named_scope("attn_kernel"):
+        out = fn(qg, kp, vp, scale=scale, policy=policy, window=cfg.window,
+                 prefix_len=0, chunk=chunk, q_offset=q_offset, fmt=fmt)
     out = out.reshape(B, S, cfg.q_dim)
     return pdot(out, p["wo"], policy, "attn_w"), new_cache

@@ -288,22 +288,23 @@ def _nonlin(x, name):
 
 
 def ffn_apply(p, x, policy, cfg):
-    if _impl(policy) == "qmm_pallas" and isinstance(p["w_in"], QTensor) \
-            and isinstance(p.get("w_gate", p["w_in"]), QTensor):
-        return _ffn_apply_fused(p, x, policy, cfg)
-    h = pdot(x, p["w_in"], policy, "ffn_w", out_act=False)
-    if "b_in" in p:
-        h = h + p["b_in"].astype(jnp.float32)
-    a = _nonlin(h, cfg.act_fn)
-    if "w_gate" in p:
-        g = pdot(x, p["w_gate"], policy, "ffn_w", out_act=False)
-        a = a * g
-    a = act_cast(a, policy)
-    y = pdot(a, p["w_out"], policy, "ffn_w")
-    if "b_out" in p:
-        y = act_cast(y.astype(jnp.float32) + p["b_out"].astype(jnp.float32),
-                     policy)
-    return y
+    with jax.named_scope("ffn"):
+        if _impl(policy) == "qmm_pallas" and isinstance(p["w_in"], QTensor) \
+                and isinstance(p.get("w_gate", p["w_in"]), QTensor):
+            return _ffn_apply_fused(p, x, policy, cfg)
+        h = pdot(x, p["w_in"], policy, "ffn_w", out_act=False)
+        if "b_in" in p:
+            h = h + p["b_in"].astype(jnp.float32)
+        a = _nonlin(h, cfg.act_fn)
+        if "w_gate" in p:
+            g = pdot(x, p["w_gate"], policy, "ffn_w", out_act=False)
+            a = a * g
+        a = act_cast(a, policy)
+        y = pdot(a, p["w_out"], policy, "ffn_w")
+        if "b_out" in p:
+            y = act_cast(y.astype(jnp.float32)
+                         + p["b_out"].astype(jnp.float32), policy)
+        return y
 
 
 def _ffn_apply_fused(p, x, policy, cfg):
@@ -383,7 +384,8 @@ def lm_head_loss(x, head_w, labels, policy, n_chunks: int = 4,
 
 
 def lm_logits(x, head_w, policy):
-    y = pdot(x, head_w, policy, "embed_w", out_act=False)
-    if policy.mode == "emulated":
-        return quantize(y, policy.fmt("logits"))
-    return y.astype(policy.dtype("logits"))
+    with jax.named_scope("lm_head"):
+        y = pdot(x, head_w, policy, "embed_w", out_act=False)
+        if policy.mode == "emulated":
+            return quantize(y, policy.fmt("logits"))
+        return y.astype(policy.dtype("logits"))

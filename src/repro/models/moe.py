@@ -41,11 +41,12 @@ def moe_apply(p, x, cfg, policy: PrecisionPolicy):
     Dispatches to the shard_map expert-parallel path when the config asks
     for it and a mesh with a "model" axis is active (see moe_apply_sharded).
     """
-    if getattr(cfg, "moe_impl", "dense") == "shard_map":
-        mesh = compat.get_abstract_mesh()
-        if mesh is not None and "model" in (mesh.axis_names or ()):
-            return moe_apply_sharded(p, x, cfg, policy, mesh)
-    return _moe_apply_global(p, x, cfg, policy)
+    with jax.named_scope("ffn"):
+        if getattr(cfg, "moe_impl", "dense") == "shard_map":
+            mesh = compat.get_abstract_mesh()
+            if mesh is not None and "model" in (mesh.axis_names or ()):
+                return moe_apply_sharded(p, x, cfg, policy, mesh)
+        return _moe_apply_global(p, x, cfg, policy)
 
 
 def route(p, xt, cfg, policy: PrecisionPolicy):

@@ -139,3 +139,63 @@ def test_qmm_ffn_compiles(one_chip, fmt):
     ct = fmt.container_dtype
     _compile_for_chip(fn, one_chip, ((8, 4096), jnp.float32),
                       ((4096, 11008), ct), ((4096, 11008), ct))
+
+
+def test_scopes_name_the_kernel_and_change_nothing_else(one_chip,
+                                                        monkeypatch):
+    """The reduced granite decode step (binary8 pool, gather bridge,
+    ``flash_decode``) compiled for the chip with and without the
+    ``jax.named_scope`` scopes: the scopes reach the operations' metadata
+    and give the Mosaic call's instruction its scope's name; nothing else
+    in the program changes."""
+    import contextlib
+    import dataclasses
+    import re
+
+    from repro import configs
+    from repro.engine.worker import DecodeWorker
+    from repro.kernels import paged_cache
+    from repro.models.registry import build_from_config
+    from repro.tuning.artifact import load_policy
+
+    cfg = configs.get("granite-moe-1b-a400m", reduced=True)
+    pol = dataclasses.replace(load_policy("transprecision"),
+                              decode_impl="flash_pallas")
+    model = build_from_config(cfg)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, page, per_seq = 4, 128, 2
+
+    def pools():
+        return [paged_cache.init_paged_cache(
+            slots, slots * per_seq, page, per_seq, cfg.n_kv, cfg.head_dim,
+            pol.dtype("kv_cache", layer=li)) for li in range(cfg.n_layers)]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    args = on_chip((
+        jax.eval_shape(lambda k: model.init_params(k, pol),
+                       jax.random.PRNGKey(0)),
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32), jax.eval_shape(pools),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+
+    def program():
+        text = DecodeWorker(model, pol)._step.lower(*args).compile() \
+            .as_text()
+        assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+        body = text[min(text.find(k) for k in ("\n%", "\nENTRY")
+                        if k in text):]
+        return text, re.sub(r", metadata=\{[^}]*\}", "", body)
+
+    scoped, scoped_body = program()
+    ops = set(re.findall(r'op_name="([^"]*)"', scoped))
+    for scope in ("kv_append", "gather_pages", "attn_kernel", "ffn",
+                  "lm_head"):
+        assert any(f"/{scope}/" in o for o in ops), scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    _, plain_body = program()
+    kernels = re.findall(r"%attn_kernel\.\d+ = ", scoped_body)
+    assert len(kernels) == cfg.n_layers
+    assert re.sub(r"%attn_kernel\.", "%_step.", scoped_body) == plain_body
