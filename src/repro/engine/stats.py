@@ -58,6 +58,9 @@ class EngineStats:
         # largest contiguous K/V staging buffer any prefill step built, in
         # tokens (chunked prefill: one chunk; whole-prompt: the prompt)
         self.peak_prefill_transient_tokens = 0
+        # whole-prompt prefills written into every layer's pool by one
+        # donated program call (the chunked path writes inside its own)
+        self.pool_write_calls = 0
         # resilience: fault-injection and recovery accounting (see
         # docs/resilience.md) -- every injected fault must be explained by
         # some combination of these counters
@@ -107,6 +110,10 @@ class EngineStats:
     def note_prefill_transient(self, n_tokens: int) -> None:
         self.peak_prefill_transient_tokens = max(
             self.peak_prefill_transient_tokens, int(n_tokens))
+
+    def note_pool_write(self) -> None:
+        """One fused pool-write call of a whole-prompt prefill."""
+        self.pool_write_calls += 1
 
     def note_decode_tokens(self, n: int) -> None:
         self.decode_tokens += int(n)
@@ -225,6 +232,7 @@ class EngineStats:
                 self.peak_prefill_transient_tokens,
             "peak_prefill_transient_bytes":
                 self.peak_prefill_transient_tokens * int(kv_bytes_per_token),
+            "pool_write_calls": self.pool_write_calls,
             # resilience accounting (docs/resilience.md): counters must
             # explain every injected fault, and failures are classified
             # results on the requests, never hangs

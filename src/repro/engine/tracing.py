@@ -24,7 +24,7 @@ STEP = "engine.step"                  # root: one engine iteration
 ADMIT = "engine.admit"                # pool allocation, transport.begin
 PREFILL = "engine.prefill"            # one task's chunk or whole prompt
 PREFILL_DISPATCH = "prefill.dispatch"  # batch build, prefill program call
-POOL_WRITE = "prefill.pool_write"     # eager per-layer write_prefill
+POOL_WRITE = "prefill.pool_write"     # one donated write into every pool
 ABSORB = "transport.absorb"           # page handoff into the decode pool
 FINISH = "transport.finish"           # ragged tail, device sequence length
 FIRST_TOKEN = "prefill.first_token"   # the prompt's first token to host

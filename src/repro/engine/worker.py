@@ -9,9 +9,10 @@ buffer drops from O(prompt_len) to O(page_size) per layer, and the
 scheduler interleaves a decode step between chunks so long prompts never
 stall the decode batch.
 
-``slot`` / ``q_offset`` are static jit arguments: the XLA prefill path
-sizes its causal masks with Python arithmetic on ``q_offset``, so each
-(chunk length, offset) pair compiles once and is reused across requests.
+``slot`` / ``q_offset`` are static arguments of the chunk program: the
+XLA prefill path sizes its causal masks with Python arithmetic on
+``q_offset``, so each (chunk length, offset) pair compiles once and is
+reused across requests.
 """
 from __future__ import annotations
 
@@ -55,8 +56,12 @@ class PrefillWorker:
     chunk_tokens > 0 on a decoder-only arch: page-granular chunked prefill
     (transient staging = one chunk).  chunk_tokens == 0, or a prefix-LM
     arch whose prefix rows need the whole-sequence path: one-shot
-    ``Model.prefill`` followed by a bulk ``write_prefill`` (transient
-    staging = the whole prompt, the old serve.py behavior).
+    ``Model.prefill`` followed by one ``_write_pools`` call, a jitted
+    ``paged_cache.write_prefill_layers`` that writes the prompt into
+    every attention layer's pool and donates the K/V pools, so each is
+    updated in place rather than copied (transient staging = the whole
+    prompt, the old serve.py behavior).  ``slot`` is traced there, so the
+    write compiles once per prompt length.
     """
 
     def __init__(self, model, cfg, policy, transport, stats, *,
@@ -74,6 +79,23 @@ class PrefillWorker:
         # capacity=None: the transient contiguous prefill cache is
         # prompt-sized, immediately rewritten into pages
         self._whole = jax.jit(lambda p, b: model.prefill(p, b, policy, None))
+        self._attn = [li for li, kind in enumerate(cfg.attn_pattern)
+                      if kind == "attn"]
+
+        def _write_pools(k_pools, v_pools, tables, lens, slot, ks, vs):
+            caches = [paged_cache.PagedKVCache(*c)
+                      for c in zip(k_pools, v_pools, tables, lens)]
+            out = paged_cache.write_prefill_layers(
+                caches, slot, [k[0] for k in ks], [v[0] for v in vs])
+            # outputs in the donated inputs' order: JAX hands a donated
+            # buffer to the first output of its shape and dtype, and any
+            # other order makes XLA copy the pools to honour that pairing
+            return ([c.k_pool for c in out], [c.v_pool for c in out],
+                    [c.seq_lens for c in out])
+
+        # the pools are donated: undonated, one program over every layer
+        # would hold a second copy of each pool at once
+        self._write_pools = jax.jit(_write_pools, donate_argnums=(0, 1))
 
     def next_tokens(self, task: PrefillTask) -> int:
         """Prompt tokens the next :meth:`step` of ``task`` prefills."""
@@ -108,12 +130,20 @@ class PrefillWorker:
             logits, one_states = self._whole(self.transport.params, batch)
         with tracing.span(tracing.POOL_WRITE):
             for li, kind in enumerate(self.cfg.attn_pattern):
-                if kind == "attn":
-                    view_states[li] = paged_cache.write_prefill(
-                        view_states[li], slot,
-                        one_states[li].k[0], one_states[li].v[0])
-                else:
+                if kind != "attn":
                     task.pstates[li] = one_states[li]
+            if self._attn:
+                caches = [view_states[li] for li in self._attn]
+                written = self._write_pools(
+                    [c.k_pool for c in caches], [c.v_pool for c in caches],
+                    [c.block_tables for c in caches],
+                    [c.seq_lens for c in caches], slot,
+                    [one_states[li].k for li in self._attn],
+                    [one_states[li].v for li in self._attn])
+                for li, c, k, v, n in zip(self._attn, caches, *written):
+                    view_states[li] = c._replace(k_pool=k, v_pool=v,
+                                                 seq_lens=n)
+                self.stats.note_pool_write()
         self.stats.note_prefill_transient(task.n_tokens)
         task.offset = task.n_tokens
         task.done = True

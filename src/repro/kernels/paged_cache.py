@@ -23,7 +23,8 @@ Two halves, deliberately split:
     The *device* state -- a pytree of arrays (pools, block tables, sequence
     lengths) that flows through ``jax.jit`` decode steps unchanged in
     structure.  All device ops (:func:`append_decode`,
-    :func:`write_prefill`, :func:`release_slot`) are functional updates.
+    :func:`write_prefill`, :func:`write_prefill_layers`,
+    :func:`release_slot`) are functional updates.
 
 :class:`PagePool`
     The *host* allocator -- a free list plus per-slot page ownership.  Page
@@ -132,9 +133,11 @@ def init_paged_cache(n_slots: int, num_pages: int, page_size: int,
                      pages_per_seq: int, n_kv: int, head_dim: int,
                      dtype) -> PagedKVCache:
     validate_page_size(page_size)
-    z = jnp.zeros((num_pages, page_size, n_kv, head_dim), dtype)
+    shape = (num_pages, page_size, n_kv, head_dim)
+    # two buffers, never one shared: a program that donates both pools
+    # (the prefill worker's pool write) cannot donate one buffer twice
     return PagedKVCache(
-        k_pool=z, v_pool=z,
+        k_pool=jnp.zeros(shape, dtype), v_pool=jnp.zeros(shape, dtype),
         block_tables=jnp.full((n_slots, pages_per_seq), -1, jnp.int32),
         seq_lens=jnp.zeros((n_slots,), jnp.int32))
 
@@ -241,6 +244,59 @@ def write_prefill(cache: PagedKVCache, slot, k, v) -> PagedKVCache:
     recorded length clamped to what was actually mapped).
     """
     return write_chunk(cache, slot, k, v, 0)
+
+
+def _write_prompt_pages(pool, phys, x):
+    """``_scatter_tokens`` for a prompt at offset 0, one whole page at a
+    time: row ``r`` of logical page ``p`` gets ``x[p * page + r]``.  Rows
+    past the prompt keep their bytes and unmapped (``phys < 0``) pages are
+    left alone (their update rewrites page 0 with what it holds), exactly
+    as the per-token scatter leaves them."""
+    n, page = phys.shape[0], pool.shape[1]
+    S = min(x.shape[0], n * page)
+    x = jnp.pad(x[:S].astype(pool.dtype),
+                ((0, n * page - S),) + ((0, 0),) * (x.ndim - 1))
+    x = x.reshape((n, page) + x.shape[1:])
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, page) + x.shape[2:], 1)
+
+    def page_write(p, pool):
+        i = jnp.maximum(phys[p], 0)
+        old = jax.lax.dynamic_index_in_dim(pool, i, 0)
+        new = jax.lax.dynamic_index_in_dim(x, p, 0)
+        keep = (row < S - p * page) & (phys[p] >= 0)
+        return jax.lax.dynamic_update_index_in_dim(
+            pool, jnp.where(keep, new, old), i, 0)
+
+    return jax.lax.fori_loop(0, n, page_write, pool)
+
+
+def write_prefill_layers(caches, slot, ks, vs) -> List[PagedKVCache]:
+    """:func:`write_prefill` into every layer's pool at once.
+
+    caches: one :class:`PagedKVCache` per attention layer; ks / vs: that
+    layer's (S, n_kv, head_dim) prompt K/V.  The result is bit-identical
+    to ``write_prefill`` per layer (same cast, same drop of unmapped or
+    over-capacity positions, same ``seq_lens[slot]`` clamp), but each pool
+    is updated in place one page at a time.  The TPU's default layout of
+    a pool depends on its shape (``(.., 128, 8, 64)`` binary8 keeps the
+    in-page row axis minor-most, ``(.., 128, 4, 128)`` is row-major), and
+    a scatter of token rows or of whole pages relayouts every pool whose
+    layout does not suit it; a one-page update is cheap in any layout.
+    Meant to run inside one jitted program that donates the pools
+    (``engine.worker.PrefillWorker``).
+    """
+    out = []
+    for c, k, v in zip(caches, ks, vs):
+        S, page = k.shape[0], c.page_size
+        n = min(-(-S // page), c.pages_per_seq)
+        phys = c.block_tables[slot, :n]
+        rows = jnp.clip(S - page * jnp.arange(n), 0, page)
+        n_mapped = jnp.sum(jnp.where(phys >= 0, rows, 0).astype(jnp.int32))
+        out.append(c._replace(
+            k_pool=_write_prompt_pages(c.k_pool, phys, k),
+            v_pool=_write_prompt_pages(c.v_pool, phys, v),
+            seq_lens=c.seq_lens.at[slot].set(n_mapped)))
+    return out
 
 
 def set_seq_len(cache: PagedKVCache, slot, n) -> PagedKVCache:

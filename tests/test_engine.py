@@ -82,6 +82,31 @@ def test_chunked_prefill_transient_is_one_page(served_model):
     assert runs["chunked"][1] == runs["whole"][1]  # same greedy tokens
 
 
+@pytest.mark.parametrize("chunk", [0, None], ids=["whole", "chunked"])
+@pytest.mark.parametrize("transport", [ColocatedTransport, StreamedTransport])
+def test_whole_prompt_pool_write_matches_reference(served_model, transport,
+                                                   chunk):
+    """Whole-prompt prefill writes every layer's pool in one donated call
+    per prompt, through either transport, and serves the synchronous
+    reference's tokens; the chunked path never makes that call."""
+    model, cfg, pol, params = served_model
+    prompts = _prompts(cfg, 3, 11) + _prompts(cfg, 1, 16, seed=1)
+    want = synchronous_generate(model, cfg, pol, params, prompts,
+                                max_new=5, capacity=32)
+    eng = Engine(model, cfg, pol, params, slots=2, capacity=32, page_size=8,
+                 prefill_chunk=chunk, transport=transport())
+    reqs = [Request(i, list(p), 5) for i, p in enumerate(prompts)]
+    eng.run(reqs)
+    assert [r.generated for r in reqs] == want
+    s = eng.stats.summary()
+    if chunk == 0:
+        assert s["pool_write_calls"] == sum(
+            eng.stats.prefill_chunks.values()) == len(reqs)
+        assert eng.prefill_worker._write_pools._cache_size() == 2
+    else:
+        assert s["pool_write_calls"] == 0
+
+
 class _CountingTransport(ColocatedTransport):
     def __init__(self):
         self.aborts = 0

@@ -9,6 +9,8 @@ free/realloc (stale bytes must be invisible, including under pool
 sharding), the device cache ops, the host allocator's bookkeeping, and
 the model/serve-level PagedKVCache wiring.
 """
+import types
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -159,6 +161,13 @@ def test_append_decode_skips_unmapped_slots():
     np.testing.assert_array_equal(np.asarray(cache.seq_lens), [0, 0])
 
 
+def _bits(x):
+    """An array's raw bits (float8/16/32 pools compare bit for bit)."""
+    n = np.dtype(x.dtype).itemsize * 8
+    return np.asarray(jax.lax.bitcast_convert_type(
+        x, getattr(jnp, f"uint{n}")))
+
+
 @pytest.mark.parametrize("chunk", [5, 7, 8, 12])
 @pytest.mark.parametrize("fmt", PAPER_FORMATS, ids=lambda f: f.name)
 def test_write_chunk_bit_identical_to_write_prefill(fmt, chunk):
@@ -188,13 +197,8 @@ def test_write_chunk_bit_identical_to_write_prefill(fmt, chunk):
         chunked = paged_cache.write_chunk(chunked, 0, k[off:off + c],
                                           v[off:off + c], off)
 
-    def bits(x):
-        n = np.dtype(x.dtype).itemsize * 8
-        return np.asarray(jax.lax.bitcast_convert_type(
-            x, getattr(jnp, f"uint{n}")))
-
-    np.testing.assert_array_equal(bits(chunked.k_pool), bits(whole.k_pool))
-    np.testing.assert_array_equal(bits(chunked.v_pool), bits(whole.v_pool))
+    np.testing.assert_array_equal(_bits(chunked.k_pool), _bits(whole.k_pool))
+    np.testing.assert_array_equal(_bits(chunked.v_pool), _bits(whole.v_pool))
     np.testing.assert_array_equal(np.asarray(chunked.seq_lens),
                                   np.asarray(whole.seq_lens))
     assert int(chunked.seq_lens[0]) == S
@@ -217,6 +221,179 @@ def test_write_chunk_respects_table_mask_and_capacity():
     # the final length after page copies)
     out = paged_cache.set_seq_len(out, 0, 5)
     assert int(out.seq_lens[0]) == 5
+
+
+def _assert_caches_identical(got, want):
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        if w is None:
+            assert g is None
+            continue
+        for a, b in zip(g, w):
+            np.testing.assert_array_equal(_bits(a), _bits(b))
+
+
+def _stale_pools(rng, n_layers, fmt, *, num_pages, page, per_seq, n_slots,
+                 H, dh, pool):
+    """Per-layer caches whose pools hold random stale bytes, behind the
+    allocator's tables."""
+    caches = []
+    for _ in range(n_layers):
+        c = paged_cache.init_paged_cache(n_slots, num_pages, page, per_seq,
+                                         H, dh, fmt.native_dtype)
+        junk = [jax.lax.bitcast_convert_type(
+            encode(jnp.asarray(rng.normal(size=c.k_pool.shape),
+                               jnp.float32), fmt), fmt.native_dtype)
+            for _ in range(2)]
+        caches.append(paged_cache.set_block_tables(
+            c._replace(k_pool=junk[0], v_pool=junk[1]), pool.tables))
+    return caches
+
+
+def _prompt_kv(rng, n_layers, S, H, dh):
+    return [jnp.asarray(rng.normal(size=(1, S, H, dh)), jnp.float32)
+            for _ in range(2 * n_layers)]
+
+
+WRITE_FORMATS = [f for f in PAPER_FORMATS if f.name != "binary32"]
+
+
+@pytest.mark.parametrize("S,mapped,length", [
+    (13, 13, 13),   # ragged last page: rows 13..15 keep their stale bytes
+    (20, 12, 16),   # tail past the mapped pages: tokens 16..19 dropped
+    (28, 24, 24),   # past capacity (3 pages of 8): tokens 24..27 dropped
+], ids=["ragged", "unmapped-tail", "over-capacity"])
+@pytest.mark.parametrize("fmt", WRITE_FORMATS, ids=lambda f: f.name)
+def test_write_prefill_layers_bit_identical_to_write_prefill(fmt, S, mapped,
+                                                             length):
+    """The fused all-layer write, jitted with the slot traced and the pools
+    donated (as the prefill worker runs it), leaves every layer's pools,
+    tables and lengths bit-identical to the eager per-layer write_prefill,
+    in slot 2, into pages reused from a freed slot that hold stale
+    bytes."""
+    rng = np.random.default_rng(S)
+    page, per_seq, num_pages, n_slots, H, dh, L = 8, 3, 7, 3, 2, 16, 3
+    pool = paged_cache.PagePool(num_pages, page, n_slots, per_seq)
+    assert pool.allocate(0, 24) and pool.allocate(1, 8)
+    pool.free_slot(0)
+    assert pool.allocate(2, mapped)
+    caches = _stale_pools(rng, L, fmt, num_pages=num_pages, page=page,
+                          per_seq=per_seq, n_slots=n_slots, H=H, dh=dh,
+                          pool=pool)
+    kv = _prompt_kv(rng, L, S, H, dh)
+    ks, vs = kv[:L], kv[L:]
+    want = [paged_cache.write_prefill(c, 2, k[0], v[0])
+            for c, k, v in zip(caches, ks, vs)]
+    want = jax.tree.map(np.asarray, want)
+
+    def fused(k_pools, v_pools, rest, slot):
+        cs = [paged_cache.PagedKVCache(k, v, *r)
+              for k, v, r in zip(k_pools, v_pools, rest)]
+        return paged_cache.write_prefill_layers(
+            cs, slot, [k[0] for k in ks], [v[0] for v in vs])
+
+    got = jax.jit(fused, donate_argnums=(0, 1))(
+        [c.k_pool for c in caches], [c.v_pool for c in caches],
+        [(c.block_tables, c.seq_lens) for c in caches], jnp.int32(2))
+    _assert_caches_identical(got, want)
+    assert [int(c.seq_lens[2]) for c in got] == [length] * L
+
+
+class _Transport:
+    params = None
+
+    def to_prefill(self, tree):
+        return tree
+
+
+@pytest.fixture(scope="module")
+def mixed_worker():
+    """The prefill worker over recurrentgemma's rglru/attention pattern."""
+    from repro.engine.stats import EngineStats
+    from repro.engine.worker import PrefillWorker
+    from repro.models.registry import build
+    model, cfg = build("recurrentgemma-2b", reduced=True)
+    assert {"attn"} < set(cfg.attn_pattern)
+    pol = binary32_policy()
+    params = model.init_params(jax.random.PRNGKey(0), pol)
+    tr = _Transport()
+    tr.params = params
+    return cfg, PrefillWorker(model, cfg, pol, tr, EngineStats(),
+                              chunk_tokens=0)
+
+
+def test_prefill_worker_writes_only_attention_layers(mixed_worker):
+    """A whole-prompt step writes the attention layers' pools exactly as
+    the eager per-layer loop did, hands the recurrent layers' states to
+    the task, leaves the recurrent layers' view entries alone, and counts
+    one fused call; a second slot at the same prompt length reuses the
+    compiled writer."""
+    from repro.engine.worker import PrefillTask, make_batch
+    cfg, worker = mixed_worker
+    rng = np.random.default_rng(2)
+    page, per_seq, n_slots, S = 8, 4, 3, 13
+    pool = paged_cache.PagePool(n_slots * per_seq, page, n_slots, per_seq)
+    assert pool.allocate(1, S) and pool.allocate(2, S)
+    attn = [li for li, k in enumerate(cfg.attn_pattern) if k == "attn"]
+    assert len(attn) < cfg.n_layers
+
+    def view():
+        return [paged_cache.set_block_tables(paged_cache.init_paged_cache(
+            n_slots, n_slots * per_seq, page, per_seq, cfg.n_kv,
+            cfg.head_dim, jnp.float32), pool.tables) if li in attn else None
+            for li in range(cfg.n_layers)]
+
+    request = types.SimpleNamespace(
+        prompt=rng.integers(0, cfg.vocab, S).tolist())
+    _, one = worker._whole(worker.transport.params,
+                           make_batch(cfg, request))
+    for slot in (1, 2):
+        states = view()
+        want = [None if s is None else paged_cache.write_prefill(
+            s, slot, one[li].k[0], one[li].v[0])
+            for li, s in enumerate(states)]
+        want = jax.tree.map(np.asarray, want)
+        task = PrefillTask(request, slot, S)
+        task.pstates = [None] * cfg.n_layers
+        got = worker.step(task, view(), slot)
+        _assert_caches_identical(got, want)
+        for li, kind in enumerate(cfg.attn_pattern):
+            if kind == "attn":
+                assert task.pstates[li] is None
+            else:
+                assert got[li] is None and task.pstates[li] is not None
+        assert task.done and task.offset == S
+    assert worker.stats.pool_write_calls == 2
+    assert worker._write_pools._cache_size() == 1
+
+
+def test_pool_writer_aliases_every_pool(mixed_worker):
+    """The compiled writer updates each K and V pool in place: every pool
+    parameter is aliased to its own layer's output pool (a donated buffer
+    paired with another output, or not reused, is copied instead)."""
+    import re
+    cfg, worker = mixed_worker
+    attn = [li for li, k in enumerate(cfg.attn_pattern) if k == "attn"]
+    c = paged_cache.init_paged_cache(2, 4, 8, 2, cfg.n_kv, cfg.head_dim,
+                                     jnp.float8_e5m2)
+    kv = jnp.zeros((1, 12, cfg.n_kv, cfg.head_dim), jnp.float32)
+    n = len(attn)
+    text = worker._write_pools.lower(
+        [c.k_pool] * n, [c.v_pool] * n, [c.block_tables] * n,
+        [c.seq_lens] * n, 1, [kv] * n, [kv] * n).compile().as_text()
+    header = next(ln for ln in text.splitlines() if ln.startswith("HloModule"))
+    pairs = {(int(o), int(p)) for o, p in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, may-alias\)", header)}
+    assert pairs == {(i, i) for i in range(2 * n)}
+
+
+def test_init_paged_cache_distinct_pools():
+    """K and V are two buffers: a program donating both pools cannot be
+    handed one buffer twice."""
+    c = paged_cache.init_paged_cache(2, 4, 8, 2, 1, 8, jnp.float8_e5m2)
+    assert c.k_pool is not c.v_pool
+    assert (c.k_pool.unsafe_buffer_pointer()
+            != c.v_pool.unsafe_buffer_pointer())
 
 
 def test_validate_page_size():

@@ -199,3 +199,44 @@ def test_scopes_name_the_kernel_and_change_nothing_else(one_chip,
     kernels = re.findall(r"%attn_kernel\.\d+ = ", scoped_body)
     assert len(kernels) == cfg.n_layers
     assert re.sub(r"%attn_kernel\.", "%_step.", scoped_body) == plain_body
+
+
+@pytest.mark.parametrize("n_kv,dh", [
+    pytest.param(8, 64, id="granite-kv8-dh64"),
+    pytest.param(4, 128, id="yi-kv4-dh128"),
+])
+def test_pool_writer_updates_pools_in_place(one_chip, n_kv, dh):
+    """The whole-prompt pool write at the chat and yi cells' size (24
+    layers, 512 binary8 pages of 128 rows, a 1024-token prompt) compiled
+    for the chip: every K and V pool is aliased to its own output, no
+    pool-sized copy is left in the program, and its temporaries stay
+    under one pool's bytes.  The chip lays these two pool shapes out
+    differently, and a scatter of rows or of pages relayouts the pools of
+    one of them through whole-pool copies."""
+    import math
+    import re
+    import types
+
+    from repro.engine.worker import PrefillWorker
+
+    cfg = types.SimpleNamespace(attn_pattern=("attn",) * 24, prefix_len=0,
+                                encoder_layers=0)
+    worker = PrefillWorker(None, cfg, None, None, None, chunk_tokens=0)
+    L, f8 = len(worker._attn), jnp.float8_e5m2
+    pool = (512, 128, n_kv, dh)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = worker._write_pools.lower(
+        [on_chip(pool, f8)] * L, [on_chip(pool, f8)] * L,
+        [on_chip((32, 16), jnp.int32)] * L, [on_chip((32,), jnp.int32)] * L,
+        on_chip((), jnp.int32), [on_chip((1, 1024, n_kv, dh), f8)] * L,
+        [on_chip((1, 1024, n_kv, dh), f8)] * L).compile()
+    text = compiled.as_text()
+    pairs = {(int(o), int(p)) for o, p in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, may-alias\)", text.split("\n", 1)[0])}
+    assert pairs == {(i, i) for i in range(2 * L)}
+    shape = ",".join(map(str, pool))
+    assert not re.search(rf"= f8e5m2\[{shape}\]\S* copy", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < math.prod(pool)
