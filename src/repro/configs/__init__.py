@@ -2,7 +2,7 @@
 
 Each module exposes ``full()`` (the exact published config) and ``reduced()``
 (a small same-family config for CPU smoke tests).  ``repro.configs.get(arch)``
-resolves by id; ``ARCHS`` lists all ten assigned ids.
+resolves by id; ``ARCHS`` lists every id.
 """
 from importlib import import_module
 
@@ -17,6 +17,7 @@ ARCHS = (
     "paligemma-3b",
     "whisper-tiny",
     "recurrentgemma-2b",
+    "deepseek-v2-lite",
 )
 
 _MODULES = {a: a.replace("-", "_").replace(".", "_") for a in ARCHS}

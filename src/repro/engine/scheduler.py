@@ -79,7 +79,6 @@ from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -228,6 +227,12 @@ class Engine:
                 f"(enc-dec decode needs per-step encoder context)")
         self.attn_layers = [li for li, k in enumerate(cfg.attn_pattern)
                             if k == "attn"]
+        # what each attention layer caches per token (K and V rows, or
+        # one latent row): its pool's shape and its bytes per token
+        self.cache_specs = {li: model.cache_spec(li)
+                            for li in self.attn_layers}
+        if any(spec.latent for spec in self.cache_specs.values()):
+            self._check_latent(prefill_chunk, speculative, transport, mesh)
         if (self.attn_layers and cfg.window is not None
                 and capacity > cfg.window):
             raise ValueError(
@@ -266,9 +271,9 @@ class Engine:
         for li in self.attn_layers:
             # each attention layer owns its own pool, so the KV format may
             # vary by layer depth (tuned policies bind layers.{li}.kv_cache)
-            states[li] = paged_cache.init_paged_cache(
-                slots, self.num_pages, page, self.pages_per_seq, cfg.n_kv,
-                cfg.head_dim, policy.dtype("kv_cache", layer=li))
+            states[li] = paged_cache.init_pool(
+                self.cache_specs[li], slots, self.num_pages, page,
+                self.pages_per_seq, policy.dtype("kv_cache", layer=li))
             if mesh is not None:
                 states[li] = shard_pool(states[li], mesh)
         self.states = states
@@ -308,8 +313,8 @@ class Engine:
         self.prefill_worker = self.prefill_workers[0]
         self.decode_worker = DecodeWorker(model, policy)
         self.kv_bytes_per_token = sum(
-            cfg.n_kv * cfg.head_dim * 2
-            * np.dtype(policy.dtype("kv_cache", layer=li)).itemsize
+            self.cache_specs[li].bytes_per_token(
+                policy.dtype("kv_cache", layer=li))
             for li in self.attn_layers)
         self.spec = speculative
         if self.spec is not None:
@@ -354,6 +359,28 @@ class Engine:
                 f"({self.pages_per_seq * self.page} tokens) must both divide "
                 f"over the {n}-device 'model' axis; adjust --pool-pages / "
                 f"--capacity")
+
+    @staticmethod
+    def _check_latent(prefill_chunk, speculative, transport, mesh) -> None:
+        """A latent (MLA) pool is written by whole-prompt prefill and read
+        by one-token decode on one device; everything else is refused."""
+        why = []
+        if prefill_chunk != 0:
+            why.append("chunked prefill (pass prefill_chunk=0 for "
+                       "whole-prompt prefill)")
+        if speculative is not None:
+            why.append("speculative decoding")
+        transports = transport if isinstance(transport, (list, tuple)) \
+            else [transport]
+        if any(t is not None and not isinstance(t, ColocatedTransport)
+               for t in transports):
+            why.append("a streamed page transport")
+        if mesh is not None:
+            why.append("a sharded decode mesh")
+        if why:
+            raise ValueError(
+                "latent attention (MLA) layers do not support "
+                + ", ".join(why))
 
     def _push_tables(self, mask_slots=()) -> None:
         """Mirror the host block tables onto the device; ``mask_slots``
@@ -545,8 +572,11 @@ class Engine:
                     self.states[li] = _insert_slot(
                         self.states[li], tr.to_decode(task.pstates[li]),
                         si, self.slots)
-            am, fin = _host((jnp.argmax(task.logits[0, -1]),
-                             jnp.isfinite(task.logits[0, -1]).all()))
+            am, fin, counts = _host((jnp.argmax(task.logits[0, -1]),
+                                     jnp.isfinite(task.logits[0, -1]).all(),
+                                     task.counts))
+            if counts is not None:
+                self.stats.note_moe("prefill", *counts)
             if not bool(fin):
                 self._new_tokens += self._quarantine_and_replay(
                     si, "prefill logits")
@@ -764,7 +794,9 @@ class Engine:
                 # lockstep so the half-open probe can accept again
                 self.spec.shadow_step(self._tokens)
                 self.stats.note_degraded_step()
-            nxt_h, bad = _host((nxt, bad_d))
+            nxt_h, bad, counts = _host((nxt, bad_d,
+                                        self.decode_worker.counts))
+            self.stats.note_moe("decode", *counts)
             with tracing.span(tracing.EMIT):
                 for si in decoding:
                     if bool(bad[si]):
@@ -790,10 +822,14 @@ class Engine:
                 f"pages: {len(self.pool.quarantined)})")
         self._engine_step += 1
         with tracing.span(tracing.RECORD):
-            self.stats.step_record(
+            rec = self.stats.step_record(
                 step=self._engine_step, queue_depth=len(self._queue),
                 prefilling=ran_chunks, decoding=len(decoding),
                 new_tokens=self._new_tokens, pool_stats=self.pool.stats())
+            moe = {k: v for k, v in rec.items() if k.startswith("moe_")}
+            if any(moe.values()):
+                with tracing.counters(**moe):
+                    pass
         if self.watchdog_s is not None:
             if time.perf_counter() - t_step > self.watchdog_s:
                 self.stats.note_watchdog_trip()

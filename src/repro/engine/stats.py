@@ -61,6 +61,13 @@ class EngineStats:
         # whole-prompt prefills written into every layer's pool by one
         # donated program call (the chunked path writes inside its own)
         self.pool_write_calls = 0
+        # MoE expert layers, summed over layers: (token, expert) pairs
+        # routed to the experts this chip holds, and held experts that
+        # got at least one pair -- totals, and this step's by kind
+        # (decode step, whole-prompt prefill)
+        self.moe_routed_here = 0
+        self.moe_experts_hit = 0
+        self._step_moe: Dict[str, int] = {}
         # resilience: fault-injection and recovery accounting (see
         # docs/resilience.md) -- every injected fault must be explained by
         # some combination of these counters
@@ -114,6 +121,17 @@ class EngineStats:
     def note_pool_write(self) -> None:
         """One fused pool-write call of a whole-prompt prefill."""
         self.pool_write_calls += 1
+
+    def note_moe(self, kind: str, routed_here, experts_hit) -> None:
+        """One decode step's or prefill's MoE counters (``kind``
+        ``"decode"`` or ``"prefill"``)."""
+        routed_here, experts_hit = int(routed_here), int(experts_hit)
+        self.moe_routed_here += routed_here
+        self.moe_experts_hit += experts_hit
+        for name, v in (("routed_here", routed_here),
+                        ("experts_hit", experts_hit)):
+            key = f"moe_{name}.{kind}"
+            self._step_moe[key] = self._step_moe.get(key, 0) + v
 
     def note_decode_tokens(self, n: int) -> None:
         self.decode_tokens += int(n)
@@ -181,6 +199,8 @@ class EngineStats:
             "new_tokens": new_tokens,
         }
         rec.update({f"pool_{k}": v for k, v in pool_stats.items()})
+        rec.update(self._step_moe)
+        self._step_moe = {}
         self.records.append(rec)
         self._emit(rec)
         return rec
@@ -233,6 +253,8 @@ class EngineStats:
             "peak_prefill_transient_bytes":
                 self.peak_prefill_transient_tokens * int(kv_bytes_per_token),
             "pool_write_calls": self.pool_write_calls,
+            "moe_routed_here": self.moe_routed_here,
+            "moe_experts_hit": self.moe_experts_hit,
             # resilience accounting (docs/resilience.md): counters must
             # explain every injected fault, and failures are classified
             # results on the requests, never hangs

@@ -12,13 +12,25 @@ layers.  Where a span has a request, it records its id (``rid``); prefill
 spans also record their token count.
 
 The counters stay in :class:`~repro.engine.stats.EngineStats`; the spans
-are the timing.  The names ``engine_step`` and ``bench_enqueue`` belong to
-the benchmark harness's own spans and are never used here.
+are the timing.  A step's non-zero MoE counters are also written into the
+profile, inside ``engine.record``, as one event whose *name* carries them
+(:func:`counters`): a span's keyword arguments become XPlane statistics,
+which a reader of event names and times does not see.  The names
+``engine_step`` and ``bench_enqueue`` belong to the benchmark harness's own
+spans and are never used here.
 """
 import jax
 
 span = jax.profiler.TraceAnnotation
 step_span = jax.profiler.StepTraceAnnotation
+
+COUNTERS = "counters"                  # prefix of a counter event's name
+
+
+def counters(**values):
+    """An event named ``counters k=v k=v ...`` (sorted keys)."""
+    return span(" ".join([COUNTERS] + [f"{k}={v}" for k, v in
+                                       sorted(values.items())]))
 
 STEP = "engine.step"                  # root: one engine iteration
 ADMIT = "engine.admit"                # pool allocation, transport.begin

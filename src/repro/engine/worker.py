@@ -36,6 +36,7 @@ class PrefillTask:
         self.streamed = 0          # pages already handed to the decode pool
         self.done = False
         self.logits = None         # last-position logits once done
+        self.counts = None         # whole-prompt MoE counters (device)
         self.pstates = None        # B=1 recurrent-layer states (rwkv/rglru)
 
 
@@ -58,8 +59,8 @@ class PrefillWorker:
     arch whose prefix rows need the whole-sequence path: one-shot
     ``Model.prefill`` followed by one ``_write_pools`` call, a jitted
     ``paged_cache.write_prefill_layers`` that writes the prompt into
-    every attention layer's pool and donates the K/V pools, so each is
-    updated in place rather than copied (transient staging = the whole
+    every attention layer's pool (K and V, or one latent pool) and
+    donates them, so each is updated in place rather than copied (transient staging = the whole
     prompt, the old serve.py behavior).  ``slot`` is traced there, so the
     write compiles once per prompt length.
     """
@@ -78,7 +79,8 @@ class PrefillWorker:
             static_argnums=(4, 5))
         # capacity=None: the transient contiguous prefill cache is
         # prompt-sized, immediately rewritten into pages
-        self._whole = jax.jit(lambda p, b: model.prefill(p, b, policy, None))
+        self._whole = jax.jit(lambda p, b: model.prefill(
+            p, b, policy, None, with_counts=True))
         self._attn = [li for li, kind in enumerate(cfg.attn_pattern)
                       if kind == "attn"]
 
@@ -86,7 +88,8 @@ class PrefillWorker:
             caches = [paged_cache.PagedKVCache(*c)
                       for c in zip(k_pools, v_pools, tables, lens)]
             out = paged_cache.write_prefill_layers(
-                caches, slot, [k[0] for k in ks], [v[0] for v in vs])
+                caches, slot, [k[0] for k in ks],
+                [None if v is None else v[0] for v in vs])
             # outputs in the donated inputs' order: JAX hands a donated
             # buffer to the first output of its shape and dtype, and any
             # other order makes XLA copy the pools to honour that pairing
@@ -127,7 +130,8 @@ class PrefillWorker:
         with tracing.span(tracing.PREFILL_DISPATCH):
             batch = self.transport.to_prefill(
                 make_batch(self.cfg, task.request))
-            logits, one_states = self._whole(self.transport.params, batch)
+            logits, one_states, task.counts = self._whole(
+                self.transport.params, batch)
         with tracing.span(tracing.POOL_WRITE):
             for li, kind in enumerate(self.cfg.attn_pattern):
                 if kind != "attn":
@@ -158,21 +162,37 @@ class DecodeWorker:
     logits: the argmax AND the NaN/Inf guard (``bad[s]`` = slot ``s``'s
     last-position logits contain a non-finite value) are computed inside
     the jit, so the scheduler's single per-step host transfer carries the
-    guard verdict for free.  ``nan_mask`` is a traced ``(n_slots,)`` bool
+    guard verdict for free, and with it the MoE counters the step leaves
+    in :attr:`counts` (pairs routed to held experts, held experts hit,
+    summed over layers; device values).  ``nan_mask`` is a traced ``(n_slots,)`` bool
     argument the fault injector uses to poison one slot's logits -- all
     False (the no-fault case) compiles to the same program.
+
+    ``donate`` (default: on the TPU) donates the state, so every pool is
+    updated in place: undonated, the step writes a second copy of every
+    pool each step (DeepSeek-V2-Lite's 16 x 16384 latent pools are 4.5 GB
+    beside 6.2 GB of weights).  A donated state is deleted by the step, so
+    a caller keeps only the state the step returns.
     """
 
-    def __init__(self, model, policy):
+    def __init__(self, model, policy, *, donate: bool | None = None):
         def _step(p, t, s, nan_mask):
-            logits, s = model.decode_step(p, t, s, policy)
+            logits, s, counts = model.decode_step(p, t, s, policy,
+                                                  with_counts=True)
             logits = jnp.where(nan_mask[:, None, None], jnp.nan, logits)
             last = logits[:, -1, :]
             nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
             bad = ~jnp.isfinite(last).all(axis=-1)
-            return nxt, bad, s
+            # the state first: JAX hands a donated buffer to the first
+            # output of its shape and dtype, so each pool keeps its own
+            return s, nxt, bad, counts
 
-        self._step = jax.jit(_step)
+        if donate is None:
+            donate = jax.default_backend() == "tpu"
+        self._step = jax.jit(_step, donate_argnums=(2,) if donate else ())
+        self.counts = None
 
     def step(self, params, tokens, states, nan_mask):
-        return self._step(params, tokens, states, nan_mask)
+        states, nxt, bad, self.counts = self._step(params, tokens, states,
+                                                   nan_mask)
+        return nxt, bad, states

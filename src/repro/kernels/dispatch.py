@@ -82,6 +82,24 @@ prefill backend::
 
 Wrappers apply to the decode path only; for prefill a composed spelling
 resolves to its base backend (sequence-sharded prefill is an open item).
+
+latent decode backend (MLA; :func:`resolve_latent_decode`)::
+
+    fn(q, pool, n_valid, *, scale, v_dim, policy, block_tables)
+      q:     (B, H, W) absorbed queries; pool: (num_pages, page, W) latent
+      rows in storage dtype; -> (B, H, v_dim) f32.
+
+    ``"xla"`` is the gather-and-dequantize oracle; every other base
+    spelling runs the Pallas kernel of ``kernels/latent_attention.py``.
+    No wrapper shards a latent pool yet.
+
+latent prefill backend (MLA, un-absorbed; :func:`resolve_latent_prefill`)::
+
+    fn(q, k, v, *, scale, policy)
+      q, k:  (B, S, H, dk); v: (B, S, H, dv) -> (B, S, H, dv), causal.
+
+    The same choice of base: ``"xla"`` runs blocks of queries through
+    XLA, every other base spelling the Pallas kernel.
 """
 from __future__ import annotations
 
@@ -277,6 +295,51 @@ def resolve_decode(spec: str) -> Callable:
     for w in reversed(parts[:-1]):
         fn = _WRAPPERS[w](fn, base=parts[-1])
     return fn
+
+
+_LATENT: dict = {}
+_LATENT_PREFILL: dict = {}
+LATENT_IMPLS = ("xla", "pallas")
+
+
+def register_latent_decode(name: str) -> Callable:
+    assert name in LATENT_IMPLS, name
+
+    def deco(fn):
+        _LATENT[name] = fn
+        return fn
+    return deco
+
+
+def register_latent_prefill(name: str) -> Callable:
+    assert name in LATENT_IMPLS, name
+
+    def deco(fn):
+        _LATENT_PREFILL[name] = fn
+        return fn
+    return deco
+
+
+def _latent_base(spec: str) -> str:
+    """The ``xla`` base keeps XLA, any other base the Pallas kernels; a
+    sharded wrapper raises."""
+    parts = canonicalize_impl(validate_impl(spec, allow_none=False))
+    if len(parts) > 1:
+        raise ValueError(
+            f"decode_impl {spec!r}: no sharded wrapper serves a latent "
+            f"(MLA) pool; use a base spelling ('xla', 'flash_pallas' or "
+            f"'paged')")
+    return "xla" if parts[0] == "xla" else "pallas"
+
+
+def resolve_latent_decode(spec: str) -> Callable:
+    """Spelling -> latent decode callable (see :func:`_latent_base`)."""
+    return _LATENT[_latent_base(spec)]
+
+
+def resolve_latent_prefill(spec: str) -> Callable:
+    """Spelling -> latent prefill callable (see :func:`_latent_base`)."""
+    return _LATENT_PREFILL[_latent_base(spec)]
 
 
 def resolve_prefill(spec: str) -> Callable:

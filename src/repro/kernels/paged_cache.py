@@ -24,7 +24,11 @@ Two halves, deliberately split:
     lengths) that flows through ``jax.jit`` decode steps unchanged in
     structure.  All device ops (:func:`append_decode`,
     :func:`write_prefill`, :func:`write_prefill_layers`,
-    :func:`release_slot`) are functional updates.
+    :func:`release_slot`) are functional updates.  Two kinds of layer
+    cache share it (:class:`CacheSpec`): a K pool and a V pool of
+    ``(n_kv, head_dim)`` rows, or a *latent* pool (MLA, DeepSeek-V2) of
+    one row per token that serves as the key and, in its leading values,
+    as the value of every head (``v_pool`` is ``None``).
 
 :class:`PagePool`
     The *host* allocator -- a free list plus per-slot page ownership.  Page
@@ -91,12 +95,37 @@ def validate_page_size(page_size: int, fmt=None) -> int:
     return page_size
 
 
+# lanes of the TPU's vector tile: a latent row is stored padded to it
+LANES = 128
+
+
+class CacheSpec(NamedTuple):
+    """What one attention layer caches per token: a row of ``row`` values
+    for its key and another for its value, or, ``latent``, one row that
+    serves as both (stored padded to whole lanes, :attr:`stored_row`)."""
+    row: tuple
+    latent: bool = False
+
+    @property
+    def stored_row(self) -> tuple:
+        if not self.latent:
+            return self.row
+        return (-(-self.row[-1] // LANES) * LANES,)
+
+    def bytes_per_token(self, dtype) -> int:
+        """Bytes the pools store per token (padding included)."""
+        n = int(np.prod(self.stored_row)) * np.dtype(dtype).itemsize
+        return n if self.latent else 2 * n
+
+
 class PagedKVCache(NamedTuple):
     """Device half of the paged cache (a jit-stable pytree of arrays).
 
     k_pool / v_pool: (num_pages, page_size, n_kv, head_dim) in the policy's
         kv_cache storage dtype -- bit-identical to the packed (e, m)
-        container, exactly like the contiguous ``KVCache``.
+        container, exactly like the contiguous ``KVCache``.  A latent
+        cache has one ``(num_pages, page_size, width)`` pool of latent
+        rows in ``k_pool`` and ``v_pool`` None.
     block_tables: (n_slots, pages_per_seq) int32; entry ``[s, p]`` is the
         physical page holding positions [p*page_size, (p+1)*page_size) of
         the sequence in slot ``s``, or -1 when unmapped.
@@ -132,12 +161,20 @@ class PagedKVCache(NamedTuple):
 def init_paged_cache(n_slots: int, num_pages: int, page_size: int,
                      pages_per_seq: int, n_kv: int, head_dim: int,
                      dtype) -> PagedKVCache:
+    return init_pool(CacheSpec((n_kv, head_dim)), n_slots, num_pages,
+                     page_size, pages_per_seq, dtype)
+
+
+def init_pool(spec: CacheSpec, n_slots: int, num_pages: int,
+              page_size: int, pages_per_seq: int, dtype) -> PagedKVCache:
+    """An empty paged cache of ``spec``'s kind."""
     validate_page_size(page_size)
-    shape = (num_pages, page_size, n_kv, head_dim)
+    shape = (num_pages, page_size) + spec.stored_row
     # two buffers, never one shared: a program that donates both pools
     # (the prefill worker's pool write) cannot donate one buffer twice
     return PagedKVCache(
-        k_pool=jnp.zeros(shape, dtype), v_pool=jnp.zeros(shape, dtype),
+        k_pool=jnp.zeros(shape, dtype),
+        v_pool=None if spec.latent else jnp.zeros(shape, dtype),
         block_tables=jnp.full((n_slots, pages_per_seq), -1, jnp.int32),
         seq_lens=jnp.zeros((n_slots,), jnp.int32))
 
@@ -148,15 +185,18 @@ def _scatter_tokens(pool, phys, off, vals):
     The drop sentinel is ``num_pages`` (unambiguously out of bounds for
     ``mode="drop"``) rather than relying on negative-index semantics.
     """
+    if pool is None:        # a latent cache has no V pool
+        return None
     phys = jnp.where(phys < 0, pool.shape[0], phys)
-    return pool.at[phys, off].set(vals, mode="drop")
+    return pool.at[phys, off].set(vals.astype(pool.dtype), mode="drop")
 
 
 def append_decode(cache: PagedKVCache, k, v) -> PagedKVCache:
     """Append one decode token per slot at position ``seq_lens[s]``.
 
     k / v: (n_slots, 1, n_kv, head_dim), any float dtype (cast to the pool
-    storage dtype here).  Slots whose next position has no mapped page --
+    storage dtype here); a latent cache takes its (n_slots, 1, width) rows
+    as ``k`` and ``v`` None.  Slots whose next position has no mapped page --
     free slots, or a serving loop that forgot to extend the table -- are
     dropped and their length does NOT advance, so host and device length
     bookkeeping can never silently diverge.
@@ -167,11 +207,10 @@ def append_decode(cache: PagedKVCache, k, v) -> PagedKVCache:
     off = pos % cache.page_size
     mapped = (phys >= 0) & (pos < cache.capacity)
     phys = jnp.where(mapped, phys, -1)
-    kq = k[:, 0].astype(cache.k_pool.dtype)
-    vq = v[:, 0].astype(cache.v_pool.dtype)
     return cache._replace(
-        k_pool=_scatter_tokens(cache.k_pool, phys, off, kq),
-        v_pool=_scatter_tokens(cache.v_pool, phys, off, vq),
+        k_pool=_scatter_tokens(cache.k_pool, phys, off, k[:, 0]),
+        v_pool=_scatter_tokens(cache.v_pool, phys, off,
+                               None if v is None else v[:, 0]),
         seq_lens=jnp.where(mapped, pos + 1, pos))
 
 
@@ -197,10 +236,8 @@ def write_chunk(cache: PagedKVCache, slot, k, v, offset) -> PagedKVCache:
     phys = jnp.where(mapped, phys, -1)
     off = pos % cache.page_size
     return cache._replace(
-        k_pool=_scatter_tokens(cache.k_pool, phys, off,
-                               k.astype(cache.k_pool.dtype)),
-        v_pool=_scatter_tokens(cache.v_pool, phys, off,
-                               v.astype(cache.v_pool.dtype)),
+        k_pool=_scatter_tokens(cache.k_pool, phys, off, k),
+        v_pool=_scatter_tokens(cache.v_pool, phys, off, v),
         seq_lens=cache.seq_lens.at[slot].set(offset + n_mapped))
 
 
@@ -252,6 +289,8 @@ def _write_prompt_pages(pool, phys, x):
     past the prompt keep their bytes and unmapped (``phys < 0``) pages are
     left alone (their update rewrites page 0 with what it holds), exactly
     as the per-token scatter leaves them."""
+    if pool is None:
+        return None
     n, page = phys.shape[0], pool.shape[1]
     S = min(x.shape[0], n * page)
     x = jnp.pad(x[:S].astype(pool.dtype),
@@ -274,7 +313,8 @@ def write_prefill_layers(caches, slot, ks, vs) -> List[PagedKVCache]:
     """:func:`write_prefill` into every layer's pool at once.
 
     caches: one :class:`PagedKVCache` per attention layer; ks / vs: that
-    layer's (S, n_kv, head_dim) prompt K/V.  The result is bit-identical
+    layer's (S, n_kv, head_dim) prompt K/V (a latent layer: its (S, width)
+    rows and None).  The result is bit-identical
     to ``write_prefill`` per layer (same cast, same drop of unmapped or
     over-capacity positions, same ``seq_lens[slot]`` clamp), but each pool
     is updated in place one page at a time.  The TPU's default layout of

@@ -234,6 +234,111 @@ def qmatmul(a_payload, b_payload, fmt_a, fmt_b,
     return out[..., :M, :N]
 
 
+def _ragged_visits(group_sizes, n_tiles: int, bm: int):
+    """The (row tile, group) pairs a ragged matmul visits, in row order:
+    every tile that holds a row of group ``g``, for each non-empty group.
+    At most ``n_tiles + G - 1`` of them; the static length pads by
+    repeating the last pair (a repeat rewrites the same rows)."""
+    G = group_sizes.shape[0]
+    gs = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(gs)
+    starts = ends - gs
+    first = starts // bm
+    count = jnp.where(gs > 0, (jnp.maximum(ends, 1) - 1) // bm - first + 1, 0)
+    upto = jnp.cumsum(count)
+    v = jnp.minimum(jnp.arange(n_tiles + G - 1, dtype=jnp.int32),
+                    jnp.maximum(upto[-1] - 1, 0))
+    group = jnp.minimum(jnp.searchsorted(upto, v, side="right"),
+                        G - 1).astype(jnp.int32)
+    tile = (first[group] + v - (upto[group] - count[group])).astype(
+        jnp.int32)
+    return tile, group, starts, ends
+
+
+def _ragged_kernel(tile_ref, group_ref, start_ref, end_ref, a_ref, b_ref,
+                   o_ref, acc_ref, *, fmt_b, n_k, bm):
+    v, k = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _zero():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    b = _decode(b_ref[...], fmt_b) if fmt_b is not None else b_ref[...]
+    acc_ref[...] += jnp.dot(a_ref[...].astype(jnp.float32),
+                            b.astype(jnp.float32),
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(k == n_k - 1)
+    def _flush():
+        t, g = tile_ref[v], group_ref[v]
+        rows = t * bm + jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 0)
+        mine = (rows >= start_ref[g]) & (rows < end_ref[g])
+        # a tile's first visit owns it: rows of no group read 0
+        fresh = jnp.logical_or(v == 0, tile_ref[jnp.maximum(v - 1, 0)] != t)
+
+        @pl.when(fresh)
+        def _first():
+            o_ref[...] = jnp.where(mine, acc_ref[...], 0.0)
+
+        @pl.when(jnp.logical_not(fresh))
+        def _next():
+            o_ref[...] = jnp.where(mine, acc_ref[...], o_ref[...])
+
+
+def qmatmul_ragged(a, b_payload, group_sizes, fmt_b, *, blocks=None,
+                   interpret: bool | None = None):
+    """Grouped matmul over row groups on a packed weight stack: rows
+    ``[sum(gs[:g]), sum(gs[:g+1]))`` of ``a`` (M, K) times group ``g`` of
+    ``b_payload`` (G, K, N) -> (M, N) f32 (the dropless MoE experts).
+
+    The grid walks the (row tile, group) pairs that hold rows (scalar
+    prefetch: :func:`_ragged_visits`), so an empty group streams no
+    weight tile and each visited group's packed tiles stream once per
+    row tile of its rows; a tile shared by two groups is computed once for
+    each and keeps each group's rows.  Rows past ``sum(group_sizes)`` read
+    0 or are left undefined."""
+    fmt_b = get_format(fmt_b) if fmt_b is not None else None
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    (M, K), (G, K2, N) = a.shape, b_payload.shape
+    assert K == K2 and group_sizes.shape == (G,), (
+        a.shape, b_payload.shape, group_sizes.shape)
+    bm, bn, bk = blocks if blocks is not None else default_blocks(M, K, N)
+    bm = _round_up(min(bm, M), 8)
+    bk = _round_up(min(bk, K), max(_LANE, _sublane(b_payload.dtype)))
+    bn = _round_up(min(bn, N), _LANE)
+    pm, pn, pk = (_round_up(M, bm) - M, _round_up(N, bn) - N,
+                  _round_up(K, bk) - K)
+    a = a.astype(jnp.float32)
+    if pm or pk:
+        a = jnp.pad(a, ((0, pm), (0, pk)))
+    if pk or pn:
+        b_payload = jnp.pad(b_payload, ((0, 0), (0, pk), (0, pn)))
+    Mp, Np, Kp = M + pm, N + pn, K + pk
+    n_k = Kp // bk
+    visits = _ragged_visits(group_sizes, Mp // bm, bm)
+    kern = functools.partial(_ragged_kernel, fmt_b=fmt_b, n_k=n_k, bm=bm)
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(Np // bn, visits[0].shape[0], n_k),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda j, v, k, t, g, s, e: (t[v], k)),
+                pl.BlockSpec((None, bk, bn),
+                             lambda j, v, k, t, g, s, e: (g[v], k, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn),
+                                   lambda j, v, k, t, g, s, e: (t[v], j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
+        compiler_params=CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(*visits, a, b_payload)
+    return out[:M, :N]
+
+
 def qmm_ffn(x, w_in_payload, w_gate_payload, fmt_w, *, bias=None,
             act: str = "silu", out_fmt: Optional[FpFormat] = None,
             blocks=None, interpret: bool | None = None):

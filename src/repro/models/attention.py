@@ -88,14 +88,15 @@ import numpy as np
 from repro.core.policy import PrecisionPolicy
 from repro.kernels import dispatch, paged_cache
 from repro.kernels.paged_cache import PagedKVCache
-from .layers import act_cast, dense_init, pdot, peinsum, rope
+from .layers import (act_cast, aeinsum, dense_init, norm_init, pdot,
+                     peinsum, rmsnorm, rope)
 
 NEG_INF = -1e30
 
 
 class KVCache(NamedTuple):
-    k: jax.Array  # (B, S_max, n_kv, dh) in kv_cache dtype
-    v: jax.Array
+    k: jax.Array  # (B, S_max, n_kv, dh) in kv_cache dtype; latent rows
+    v: jax.Array  # (B, S_max, width) with v None for an MLA layer
     pos: jax.Array  # () int32 -- next write position (monotonic)
 
     @property
@@ -104,6 +105,8 @@ class KVCache(NamedTuple):
 
 
 def attn_init(key, cfg, dtype, cross: bool = False):
+    if cfg.latent:
+        return mla_init(key, cfg, dtype)
     d = cfg.d_model
     ks = jax.random.split(key, 4)
     return {
@@ -125,9 +128,21 @@ def init_cache(cfg, batch, length, policy: PrecisionPolicy,
             caches.append(None)
             continue
         cap = length if cfg.window is None else min(length, cfg.window)
+        if cfg.latent:
+            z = jnp.zeros((batch, cap) + cache_spec(cfg).stored_row, dt)
+            caches.append(KVCache(k=z, v=None,
+                                  pos=jnp.zeros((), jnp.int32)))
+            continue
         z = jnp.zeros((batch, cap, cfg.n_kv, cfg.head_dim), dt)
         caches.append(KVCache(k=z, v=z, pos=jnp.zeros((), jnp.int32)))
     return caches
+
+
+def cache_spec(cfg) -> paged_cache.CacheSpec:
+    """What one attention layer of ``cfg`` caches per token."""
+    if cfg.latent:
+        return paged_cache.CacheSpec((cfg.latent_dim,), latent=True)
+    return paged_cache.CacheSpec((cfg.n_kv, cfg.head_dim))
 
 
 def _split_heads(x, n, dh):
@@ -372,6 +387,12 @@ def mha(p, x, cfg, policy: PrecisionPolicy, *,
         (no recompute; the registry path and the cache see the same bits).
     Returns (out, new_cache).
     """
+    if cfg.latent:
+        if kv_source is not None or prefix_len or not causal:
+            raise ValueError("latent attention (MLA) is causal self-"
+                             "attention only")
+        return mla(p, x, cfg, policy, positions=positions, cache=cache,
+                   cache_capacity=cache_capacity)
     B, S, _ = x.shape
     n_kv, dh = cfg.n_kv, cfg.head_dim
     G = cfg.n_heads // n_kv
@@ -528,6 +549,7 @@ def verify_paged(p, x, cfg, policy: PrecisionPolicy, cache: PagedKVCache):
     (:func:`repro.kernels.paged_cache.truncate_seq_lens`) -- entries past
     the truncation point are stale bytes every reader masks.
     """
+    _refuse_latent(cfg, "verify_paged")
     B, K, _ = x.shape
     n_kv, dh = cfg.n_kv, cfg.head_dim
     G = cfg.n_heads // n_kv
@@ -640,6 +662,7 @@ def prefill_from_cache(p, x, cfg, policy, cache: KVCache, q_offset: int,
     Requires a non-ring cache with ``capacity >= q_offset + S``.
     Returns (out, new_cache with pos = q_offset + S).
     """
+    _refuse_latent(cfg, "prefill_from_cache")
     B, S, _ = x.shape
     n_kv, dh = cfg.n_kv, cfg.head_dim
     G = cfg.n_heads // n_kv
@@ -697,6 +720,7 @@ def prefill_paged_chunk(p, x, cfg, policy, cache: PagedKVCache, slot: int,
     attending over the slot's full addressable capacity is exact.
     Returns (out, new_cache with seq_lens[slot] = q_offset + S).
     """
+    _refuse_latent(cfg, "prefill_paged_chunk")
     B, S, _ = x.shape
     n_kv, dh = cfg.n_kv, cfg.head_dim
     G = cfg.n_heads // n_kv
@@ -737,3 +761,228 @@ def prefill_paged_chunk(p, x, cfg, policy, cache: PagedKVCache, slot: int,
                  prefix_len=0, chunk=chunk, q_offset=q_offset, fmt=fmt)
     out = out.reshape(B, S, cfg.q_dim)
     return pdot(out, p["wo"], policy, "attn_w"), new_cache
+
+
+def _refuse_latent(cfg, what: str) -> None:
+    if cfg.latent:
+        raise ValueError(
+            f"{what}: latent attention (MLA) serves whole-prompt prefill "
+            f"and one-token decode only (no chunked prefill, no "
+            f"speculative verify)")
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA, DeepSeek-V2 arXiv:2405.04434, no q-LoRA)
+# ---------------------------------------------------------------------------
+#
+# q = x W_q (heads of qk_nope + qk_rope values); [c, k_pe] = x W_kv_a with
+# c RMS-normed and k_pe (one rope key shared by all heads) rotated; per
+# head k_nope and v come from c through W_kv_b = [W_UK | W_UV].  Prefill
+# runs that formulation (un-absorbed): through XLA in blocks of queries,
+# or through a Pallas kernel that keeps every score tile in VMEM, so that
+# a long prompt's scores stay small beside the pool.  Decode runs absorbed: the
+# query goes into the latent space, q~ = [W_UK^T q_nope, q_pe], attends
+# the cached rows [c, k_pe] directly, and out = W_UV (sum p c).  The rope
+# halves rotate together (the published checkpoint pairs them
+# interleaved: with the weights drawn here, a permutation of columns).
+
+MLA_Q_BLOCK = 256      # query rows per block of the prefill attention
+
+
+def mla_init(key, cfg, dtype):
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], (d, H * cfg.head_dim), dtype=dtype),
+        "wkv_a": dense_init(ks[1], (d, cfg.latent_dim), dtype=dtype),
+        "kv_norm": norm_init(r, "rmsnorm"),
+        "wkv_b": dense_init(ks[2], (r, H * (cfg.qk_nope_head_dim
+                                           + cfg.v_head_dim)), dtype=dtype),
+        "wo": dense_init(ks[3], (H * cfg.v_head_dim, d), dtype=dtype),
+    }
+
+
+def mla_scale(cfg) -> np.float32:
+    """Softmax scale: qk head size ** -0.5, times YaRN's mscale squared."""
+    m = cfg.yarn.softmax_mscale if cfg.yarn is not None else 1.0
+    return np.float32(cfg.head_dim ** -0.5 * m)
+
+
+def _mla_project(p, x, cfg, policy, positions):
+    """(q_nope (B,S,H,nope), q_pe (B,S,H,rope) rotated, latent rows
+    (B,S,width): [c normed, k_pe rotated, zero padding])."""
+    B, S, _ = x.shape
+    nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = _split_heads(pdot(x, p["wq"], policy, "attn_w"), cfg.n_heads,
+                     cfg.head_dim)
+    kv = pdot(x, p["wkv_a"], policy, "attn_w")
+    c = rmsnorm(kv[..., :r], p["kv_norm"]["gamma"], policy)
+    q_pe = rope(q[..., nope:], positions, cfg.rope_theta, cfg.yarn)
+    k_pe = rope(kv[..., None, r:], positions, cfg.rope_theta, cfg.yarn)
+    pad = cache_spec(cfg).stored_row[0] - cfg.latent_dim
+    row = jnp.concatenate(
+        [c, k_pe[:, :, 0].astype(c.dtype), jnp.zeros((B, S, pad), c.dtype)],
+        axis=-1)
+    return q[..., :nope], q_pe, row
+
+
+def _mla_heads(p, cfg):
+    """W_kv_b as (W_UK (r, H, nope), W_UV (r, H, v))."""
+    w = p["wkv_b"].reshape(cfg.kv_lora_rank, cfg.n_heads,
+                           cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def _mla_prefill(q_nope, q_pe, row, p, cfg, policy):
+    """Causal un-absorbed attention over the prompt itself, through the
+    latent prefill backend that ``decode_impl`` names: (B, S, H, v)."""
+    B, S, H, _ = q_nope.shape
+    r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    kv = _split_heads(pdot(row[..., :r], p["wkv_b"], policy, "attn_w"), H,
+                      nope + cfg.v_head_dim)
+    k_pe = jnp.broadcast_to(row[:, :, None, r:cfg.latent_dim],
+                            (B, S, H, cfg.qk_rope_head_dim))
+    k = jnp.concatenate([kv[..., :nope], k_pe.astype(kv.dtype)], axis=-1)
+    q = jnp.concatenate([q_nope, q_pe.astype(q_nope.dtype)], axis=-1)
+    fn = dispatch.resolve_latent_prefill(decode_impl(cfg, policy))
+    return fn(q, k, kv[..., nope:], scale=mla_scale(cfg), policy=policy)
+
+
+@dispatch.register_latent_prefill("xla")
+def _latent_prefill_xla(q, k, v, *, scale, policy):
+    """``MLA_Q_BLOCK`` queries at a time against every key, masked."""
+    B, S, H, _ = q.shape
+    # head-major keys and values, laid out once for every query block
+    k = k.transpose(0, 2, 1, 3)
+    v = v.transpose(0, 2, 1, 3)
+    blk = min(MLA_Q_BLOCK, S)
+    nb = -(-S // blk)
+    q = jnp.pad(q, ((0, 0), (0, nb * blk - S), (0, 0), (0, 0)))
+    q = jnp.moveaxis(q.reshape(B, nb, blk, H, q.shape[-1]), 1, 0)
+
+    def block(args):
+        qb, i = args
+        s = peinsum("bqhd,bhkd->bhqk", qb, k, policy, "attn_w",
+                    out_act=False).astype(jnp.float32) * scale
+        causal = (jnp.arange(S)[None, :]
+                  <= (i * blk + jnp.arange(blk))[:, None])
+        s = jnp.where(causal, s, NEG_INF)
+        probs = act_cast(jax.nn.softmax(s, axis=-1), policy, "attn_probs")
+        return peinsum("bhqk,bhkd->bqhd", probs, v, policy, "attn_w")
+
+    out = jax.lax.map(block, (q, jnp.arange(nb)))
+    return jnp.moveaxis(out, 0, 1).reshape(B, nb * blk, H, -1)[:, :S]
+
+
+@dispatch.register_latent_prefill("pallas")
+def _latent_prefill_pallas(q, k, v, *, scale, policy):
+    """The Pallas kernel (kernels/latent_attention.py): each score tile
+    stays in VMEM, tiles above the diagonal are skipped."""
+    from repro.kernels.latent_attention import latent_prefill
+
+    return act_cast(latent_prefill(q, k, v, scale=scale), policy)
+
+
+def _latent_contiguous(q, rows, n_valid, *, scale, v_dim):
+    """Absorbed decode over a contiguous (B, S, W) latent cache (the
+    single-request reference path): f32 softmax and mix."""
+    s = aeinsum("bhw,bsw->bhs", q, rows) * scale
+    valid = (jnp.arange(rows.shape[1])[None, :]
+             < n_valid[:, None])[:, None]
+    s = jnp.where(valid, s, NEG_INF)
+    probs = jnp.where(valid, jax.nn.softmax(s, axis=-1), 0.0)
+    return aeinsum("bhs,bsv->bhv", probs, rows[..., :v_dim])
+
+
+def mla(p, x, cfg, policy: PrecisionPolicy, *, positions=None,
+        cache=None, cache_capacity: Optional[int] = None):
+    """Latent attention: prefill (no cache; ``cache_capacity`` also builds
+    a contiguous latent :class:`KVCache`) or one-token absorbed decode over
+    a paged latent pool or a contiguous latent cache.  Returns (out,
+    new_cache)."""
+    B, S, _ = x.shape
+    H, vd = cfg.n_heads, cfg.v_head_dim
+    paged = isinstance(cache, PagedKVCache)
+    base = 0
+    if cache is not None:
+        base = cache.seq_lens[:, None] if paged else cache.pos
+    if positions is None:
+        positions = jnp.arange(S)[None, :].astype(jnp.int32) + base
+    q_nope, q_pe, row = _mla_project(p, x, cfg, policy, positions)
+
+    if cache is None:
+        with jax.named_scope("attn_kernel"):
+            out = _mla_prefill(q_nope, q_pe, row, p, cfg, policy)
+        new_cache = None
+        if cache_capacity is not None:
+            dt = policy.dtype("kv_cache")
+            rows = jnp.zeros((B, max(cache_capacity, S), row.shape[-1]), dt)
+            rows = jax.lax.dynamic_update_slice_in_dim(
+                rows, row.astype(dt), 0, axis=1)
+            new_cache = KVCache(k=rows, v=None, pos=jnp.asarray(S, jnp.int32))
+        return pdot(out.reshape(B, S, H * vd), p["wo"], policy,
+                    "attn_w"), new_cache
+
+    if S != 1:
+        raise ValueError("latent attention decodes one token at a time")
+    w_uk, w_uv = _mla_heads(p, cfg)
+    q_c = peinsum("bhn,rhn->bhr", q_nope[:, 0], w_uk, policy, "attn_w",
+                  out_act=False)
+    pad = row.shape[-1] - cfg.kv_lora_rank - cfg.qk_rope_head_dim
+    q = jnp.concatenate([q_c.astype(jnp.float32),
+                         q_pe[:, 0].astype(jnp.float32),
+                         jnp.zeros((B, H, pad), jnp.float32)], axis=-1)
+    scale = mla_scale(cfg)
+    if paged:
+        with jax.named_scope("latent_append"):
+            new_cache = paged_cache.append_decode(cache, row, None)
+        fn = dispatch.resolve_latent_decode(decode_impl(cfg, policy))
+        with jax.named_scope("mla_decode"):
+            o_c = fn(q, new_cache.k_pool, new_cache.seq_lens, scale=scale,
+                     v_dim=cfg.kv_lora_rank, policy=policy,
+                     block_tables=new_cache.block_tables)
+    else:
+        slot = jnp.minimum(cache.pos, cache.capacity - 1)
+        rows = jax.lax.dynamic_update_slice_in_dim(
+            cache.k, row.astype(cache.k.dtype), slot, axis=1)
+        new_cache = KVCache(k=rows, v=None, pos=cache.pos + 1)
+        n_valid = jnp.broadcast_to(jnp.minimum(cache.pos + 1,
+                                               cache.capacity), (B,))
+        o_c = _latent_contiguous(q, rows, n_valid, scale=scale,
+                                 v_dim=cfg.kv_lora_rank)
+    out = peinsum("bhr,rhv->bhv", o_c, w_uv, policy, "attn_w")
+    return pdot(out.reshape(B, 1, H * vd), p["wo"], policy,
+                "attn_w"), new_cache
+
+
+def _latent_payload(pool, policy):
+    """A latent pool as the kernel reads it: the packed container bits of
+    a narrow native format (a pure bitcast), else f32 values."""
+    fmt = policy.fmt("kv_cache")
+    if policy.mode == "native" and not fmt.is_binary32:
+        return jax.lax.bitcast_convert_type(pool, fmt.container_dtype), fmt
+    return pool.astype(jnp.float32), None
+
+
+@dispatch.register_latent_decode("xla")
+def _latent_decode_xla(q, pool, n_valid, *, scale, v_dim, policy,
+                       block_tables):
+    """Gather the pool's pages and decode through XLA: the oracle."""
+    from repro.kernels.latent_attention import latent_decode_reference
+
+    payload, fmt = _latent_payload(pool, policy)
+    return latent_decode_reference(q, payload, fmt, n_valid, block_tables,
+                                   scale=scale, v_dim=v_dim)
+
+
+@dispatch.register_latent_decode("pallas")
+def _latent_decode_pallas(q, pool, n_valid, *, scale, v_dim, policy,
+                          block_tables):
+    """The Pallas kernel over the paged latent pool
+    (kernels/latent_attention.py): each row read from HBM once per step
+    for every head, at its stored width."""
+    from repro.kernels.latent_attention import latent_decode
+
+    payload, fmt = _latent_payload(pool, policy)
+    return latent_decode(q, payload, fmt, n_valid, block_tables,
+                         scale=scale, v_dim=v_dim)

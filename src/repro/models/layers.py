@@ -40,7 +40,7 @@ from repro.core.flexfloat import quantize
 from repro.core.policy import PrecisionPolicy
 from repro.core.qtensor import QTensor
 from repro.kernels import dispatch
-from repro.kernels.qmatmul import _apply_act, qmatmul, qmm_ffn
+from repro.kernels.qmatmul import _apply_act, qmatmul, qmatmul_ragged, qmm_ffn
 
 
 # ---------------------------------------------------------------------------
@@ -82,6 +82,15 @@ def pgrouped_dot(a, w, policy: PrecisionPolicy, role: str):
     """Batched expert matmul ``(E, M, K) @ (E, K, N) -> (E, M, N)`` (MoE
     grouped FFN).  Returns raw f32 (callers ``act_cast`` as needed)."""
     return dispatch.resolve_matmul(_impl(policy)).grouped(a, w, policy, role)
+
+
+def pragged_dot(a, w, group_sizes, policy: PrecisionPolicy, role: str):
+    """Grouped matmul over row groups: rows ``[sum(gs[:g]), sum(gs[:g+1]))``
+    of ``a`` (M, K) times ``w[g]`` (G, K, N) -> (M, N) raw f32 (dropless
+    MoE experts).  Rows past ``sum(group_sizes)`` are left undefined;
+    callers mask them."""
+    return dispatch.resolve_matmul(_impl(policy)).ragged(
+        a, w, group_sizes, policy, role)
 
 
 def aeinsum(expr, *ops):
@@ -146,7 +155,7 @@ def _grouped_xla(a, w, policy, role):
     if isinstance(w, QTensor):
         return jnp.einsum("eck,ekn->ecn", a.astype(jnp.float32),
                           w.dequantize(), preferred_element_type=jnp.float32)
-    if policy.mode == "native":
+    if policy.mode == "native" and not (a.dtype == w.dtype == jnp.float32):
         cd = jnp.bfloat16
         return jnp.einsum("eck,ekn->ecn", a.astype(cd), w.astype(cd),
                           preferred_element_type=jnp.float32)
@@ -155,11 +164,23 @@ def _grouped_xla(a, w, policy, role):
                       preferred_element_type=jnp.float32)
 
 
+def _ragged_xla(a, w, group_sizes, policy, role):
+    if isinstance(w, QTensor):
+        a, w = a.astype(jnp.float32), w.dequantize()
+    elif policy.mode == "native" and not (a.dtype == w.dtype == jnp.float32):
+        a, w = a.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
+    else:
+        a, w = a.astype(jnp.float32), w.astype(jnp.float32)
+    return jax.lax.ragged_dot(a, w, group_sizes.astype(jnp.int32),
+                              preferred_element_type=jnp.float32)
+
+
 @dispatch.register_matmul("xla")
 class _XlaMatmul:
     dot = staticmethod(_dot_xla)
     einsum = staticmethod(_einsum_xla)
     grouped = staticmethod(_grouped_xla)
+    ragged = staticmethod(_ragged_xla)
 
 
 # -- the "qmm_pallas" backend ------------------------------------------------
@@ -199,11 +220,20 @@ def _grouped_qmm(a, w, policy, role):
     return qmatmul(a.astype(jnp.float32), w.payload, None, w.fmt)
 
 
+def _ragged_qmm(a, w, group_sizes, policy, role):
+    if not isinstance(w, QTensor):
+        return _ragged_xla(a, w, group_sizes, policy, role)
+    # the dropless expert layer: each held expert's packed block streams
+    # through one kernel, for the row tiles of the pairs routed to it
+    return qmatmul_ragged(a, w.payload, group_sizes, w.fmt)
+
+
 @dispatch.register_matmul("qmm_pallas")
 class _QmmMatmul:
     dot = staticmethod(_dot_qmm)
     einsum = staticmethod(_einsum_qmm)
     grouped = staticmethod(_grouped_qmm)
+    ragged = staticmethod(_ragged_qmm)
 
 
 def act_cast(x, policy: PrecisionPolicy, role: str = "act"):
@@ -250,13 +280,41 @@ def norm_init(d, kind):
 # rotary position embeddings (f32 math)
 # ---------------------------------------------------------------------------
 
-def rope(x, positions, theta: float):
-    """x: (..., S, H, dh); positions: (..., S)."""
-    dh = x.shape[-1]
+def rope_freqs(dh: int, theta: float, yarn=None) -> np.ndarray:
+    """Rotary frequencies of a ``dh``-wide head, (dh // 2,) float32.  With
+    ``yarn`` (:class:`~repro.models.base.Yarn`), DeepSeek-V2's blend of
+    interpolated (``/ factor``) and extrapolated frequencies: a linear
+    ramp over the dimensions whose wavelengths lie between ``beta_fast``
+    and ``beta_slow`` rotations of the original context."""
     half = dh // 2
     freqs = np.exp(-np.log(theta) * np.arange(half) / half)  # (half,)
+    if yarn is None:
+        return freqs.astype(np.float32)
+
+    def dim_of(rot):
+        return (dh * math.log(yarn.original_max / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), dh - 1)
+    ramp = np.clip((np.arange(half) - low)
+                   / ((high - low) if high != low else 0.001), 0.0, 1.0)
+    keep = 1.0 - ramp                    # 1 = extrapolate (fast dims)
+    return (freqs / yarn.factor * (1.0 - keep)
+            + freqs * keep).astype(np.float32)
+
+
+def rope(x, positions, theta: float, yarn=None):
+    """x: (..., S, H, dh); positions: (..., S).  The two halves of each
+    head rotate together (``yarn``: :func:`rope_freqs`)."""
+    dh = x.shape[-1]
+    half = dh // 2
+    freqs = rope_freqs(dh, theta, yarn)
     ang = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None and yarn.rope_mscale != 1.0:
+        cos = cos * np.float32(yarn.rope_mscale)
+        sin = sin * np.float32(yarn.rope_mscale)
     cos = cos[..., :, None, :]  # broadcast over heads
     sin = sin[..., :, None, :]
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)

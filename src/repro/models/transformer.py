@@ -25,6 +25,11 @@ from .layers import (apply_norm, dense_init, embed_lookup, ffn_apply,
                      residual_add)
 
 
+def _no_counts():
+    """MoE counters of a call without expert layers."""
+    return jnp.zeros((moe_mod.N_COUNTS,), jnp.int32)
+
+
 class Model:
     def __init__(self, cfg: ModelConfig):
         self.cfg = cfg
@@ -58,11 +63,17 @@ class Model:
         if not cfg.tied_embeddings:
             params["head"] = dense_init(keys[1], (cfg.d_model, cfg.vocab),
                                         dtype=edt)
+        sliced = cfg.moe_experts_held < cfg.moe_experts
         for li, kind in enumerate(cfg.attn_pattern):
             lp = policy.at_layer(li)
             lwdt = lp.dtype("attn_w")
             lfdt = lp.dtype("ffn_w")
             k = keys[2 + li]
+            if sliced and params["layers"]:
+                # an expert share slices each whole-stack draw (moe_init):
+                # one layer's draws at a time, not every layer's at once
+                _, k = jax.lax.optimization_barrier(
+                    (params["layers"][-1], k))
             ks = jax.random.split(k, 4)
             layer: Dict[str, Any] = {"norm1": norm_init(cfg.d_model,
                                                          cfg.norm)}
@@ -76,7 +87,7 @@ class Model:
                 raise ValueError(kind)
             if kind != "rwkv":  # rwkv channel-mix lives inside its params
                 layer["norm2"] = norm_init(cfg.d_model, cfg.norm)
-                if cfg.moe_experts:
+                if cfg.is_moe_layer(li):
                     layer["ffn"] = moe_mod.moe_init(ks[1], cfg, lfdt)
                 else:
                     layer["ffn"] = ffn_init(ks[1], cfg.d_model, cfg.d_ff,
@@ -116,11 +127,19 @@ class Model:
             x = residual_add(x, ffn_apply(layer["ffn"], h, policy, cfg))
         return x
 
-    def _layer(self, layer, kind, x, policy, *, prefix_len=0, state=None,
-               enc_out=None, chunk=None, positions=None):
-        """One decoder block.  Returns (x, new_state, aux_loss)."""
+    def _ffn(self, layer, li, h, policy):
+        """The block's FFN: (out, aux loss, MoE counts)."""
         cfg = self.cfg
-        aux = jnp.zeros((), jnp.float32)
+        if cfg.is_moe_layer(li):
+            return moe_mod.moe_apply(layer["ffn"], h, cfg, policy)
+        return (ffn_apply(layer["ffn"], h, policy, cfg),
+                jnp.zeros((), jnp.float32), _no_counts())
+
+    def _layer(self, layer, li, kind, x, policy, *, prefix_len=0,
+               state=None, enc_out=None, chunk=None, positions=None):
+        """One decoder block.  Returns (x, new_state, aux_loss, counts)."""
+        cfg = self.cfg
+        aux, counts = jnp.zeros((), jnp.float32), _no_counts()
         h = apply_norm(x, layer["norm1"], policy, cfg.norm)
         if kind == "attn":
             a, new_state = attn.mha(layer["mix"], h, cfg, policy,
@@ -143,36 +162,36 @@ class Model:
         if kind == "rwkv":
             f, new_state = rwkv_mod.channel_mix(layer["mix"], h, cfg, policy,
                                                 state=new_state)
-        elif cfg.moe_experts:
-            f, aux = moe_mod.moe_apply(layer["ffn"], h, cfg, policy)
         else:
-            f = ffn_apply(layer["ffn"], h, policy, cfg)
-        return residual_add(x, f), new_state, aux
+            f, aux, counts = self._ffn(layer, li, h, policy)
+        return residual_add(x, f), new_state, aux, counts
 
     def _backbone(self, params, x, policy, *, prefix_len=0, states=None,
                   enc_out=None, chunk=None, positions=None, training=False):
         cfg = self.cfg
         new_states: List[Any] = []
         aux_total = jnp.zeros((), jnp.float32)
+        counts_total = _no_counts()
 
         for li, layer in enumerate(params["layers"]):
             st = states[li] if states is not None else None
             kind = cfg.attn_pattern[li]
             lp = policy.at_layer(li)
 
-            def run(xx, stt, layer=layer, kind=kind, lp=lp):
-                return self._layer(layer, kind, xx, lp,
+            def run(xx, stt, layer=layer, li=li, kind=kind, lp=lp):
+                return self._layer(layer, li, kind, xx, lp,
                                    prefix_len=prefix_len, state=stt,
                                    enc_out=enc_out, chunk=chunk,
                                    positions=positions)
 
             if training and cfg.remat:
                 run = jax.checkpoint(run)
-            x, ns, aux = run(x, st)
+            x, ns, aux, counts = run(x, st)
             new_states.append(ns)
             aux_total = aux_total + aux
+            counts_total = counts_total + counts
         x = apply_norm(x, params["final_norm"], policy, cfg.norm)
-        return x, new_states, aux_total
+        return x, new_states, aux_total, counts_total
 
     def _head_w(self, params):
         if self.cfg.tied_embeddings:
@@ -198,9 +217,10 @@ class Model:
             enc_out = self._encode(params, batch["encoder_embeds"]
                                    .astype(x.dtype), policy)
         chunk = cfg.attn_chunk if x.shape[1] > cfg.attn_chunk else None
-        x, _, aux = self._backbone(params, x, policy, prefix_len=prefix_len,
-                                   enc_out=enc_out, chunk=chunk,
-                                   training=True)
+        x, _, aux, _ = self._backbone(params, x, policy,
+                                      prefix_len=prefix_len,
+                                      enc_out=enc_out, chunk=chunk,
+                                      training=True)
         if prefix_len:
             x = x[:, prefix_len:]
         loss = lm_head_loss(x, self._head_w(params), labels, policy,
@@ -210,6 +230,13 @@ class Model:
         return loss
 
     # ----------------------------------------------------------------- serve
+    def cache_spec(self, li: int):
+        """What attention layer ``li`` caches per token
+        (:class:`~repro.kernels.paged_cache.CacheSpec`): K and V rows, or
+        one latent row (MLA)."""
+        assert self.cfg.attn_pattern[li] == "attn", li
+        return attn.cache_spec(self.cfg)
+
     def init_state(self, batch_size, capacity, policy):
         cfg = self.cfg
         states = []
@@ -227,8 +254,10 @@ class Model:
         return states
 
     def prefill(self, params, batch, policy: PrecisionPolicy,
-                capacity: Optional[int] = None):
-        """Full-sequence forward; returns (last-position logits, states)."""
+                capacity: Optional[int] = None, with_counts: bool = False):
+        """Full-sequence forward; returns (last-position logits, states),
+        and with ``with_counts`` the MoE counters summed over layers
+        (int32 (pairs routed to held experts, held experts hit))."""
         cfg = self.cfg
         policy = self._policy(policy)
         tokens = batch["tokens"]
@@ -249,7 +278,7 @@ class Model:
 
         # run backbone while also building decode states
         states = []
-        aux = jnp.zeros((), jnp.float32)
+        counts = _no_counts()
         for li, (kind, layer) in enumerate(zip(cfg.attn_pattern,
                                                params["layers"])):
             lp = policy.at_layer(li)
@@ -277,15 +306,15 @@ class Model:
             if kind == "rwkv":
                 f, st = rwkv_mod.channel_mix(layer["mix"], h, cfg, lp,
                                              state=st)
-            elif cfg.moe_experts:
-                f, a2 = moe_mod.moe_apply(layer["ffn"], h, cfg, lp)
-                aux = aux + a2
             else:
-                f = ffn_apply(layer["ffn"], h, lp, cfg)
+                f, _, c = self._ffn(layer, li, h, lp)
+                counts = counts + c
             x = residual_add(x, f)
             states.append(st)
         x = apply_norm(x, params["final_norm"], policy, cfg.norm)
         logits = lm_logits(x[:, -1:, :], self._head_w(params), policy)
+        if with_counts:
+            return logits, states, counts
         return logits, states
 
     def prefill_chunk(self, params, tokens, states, pstates,
@@ -338,10 +367,8 @@ class Model:
                 f, st = rwkv_mod.channel_mix(layer["mix"], h, cfg, lp,
                                              state=new_pstates[li])
                 new_pstates[li] = st
-            elif cfg.moe_experts:
-                f, _ = moe_mod.moe_apply(layer["ffn"], h, cfg, lp)
             else:
-                f = ffn_apply(layer["ffn"], h, lp, cfg)
+                f, _, _ = self._ffn(layer, li, h, lp)
             x = residual_add(x, f)
         x = apply_norm(x, params["final_norm"], policy, cfg.norm)
         logits = lm_logits(x[:, -1:, :], self._head_w(params), policy)
@@ -390,18 +417,17 @@ class Model:
             new_states[li] = st
             x = residual_add(x, a)
             h = apply_norm(x, layer["norm2"], lp, cfg.norm)
-            if cfg.moe_experts:
-                f, _ = moe_mod.moe_apply(layer["ffn"], h, cfg, lp)
-            else:
-                f = ffn_apply(layer["ffn"], h, lp, cfg)
+            f, _, _ = self._ffn(layer, li, h, lp)
             x = residual_add(x, f)
         x = apply_norm(x, params["final_norm"], policy, cfg.norm)
         logits = lm_logits(x, self._head_w(params), policy)
         return logits, new_states
 
     def decode_step(self, params, tokens, states, policy: PrecisionPolicy,
-                    enc_out=None, encoder_embeds=None):
-        """tokens: (B, 1).  Returns (logits (B, 1, V), new states)."""
+                    enc_out=None, encoder_embeds=None,
+                    with_counts: bool = False):
+        """tokens: (B, 1).  Returns (logits (B, 1, V), new states), and
+        with ``with_counts`` the MoE counters (as :meth:`prefill`)."""
         cfg = self.cfg
         policy = self._policy(policy)
         x = embed_lookup(params["embed"], tokens, policy,
@@ -409,7 +435,10 @@ class Model:
         if cfg.encoder_layers and enc_out is None:
             enc_out = self._encode(params, encoder_embeds.astype(x.dtype),
                                    policy)
-        x, new_states, _ = self._backbone(params, x, policy, states=states,
-                                          enc_out=enc_out, training=False)
+        x, new_states, _, counts = self._backbone(
+            params, x, policy, states=states, enc_out=enc_out,
+            training=False)
         logits = lm_logits(x, self._head_w(params), policy)
+        if with_counts:
+            return logits, new_states, counts
         return logits, new_states

@@ -156,3 +156,30 @@ def test_scopes_change_metadata_only(served, monkeypatch):
                         lambda name: contextlib.nullcontext())
     plain = compiled()
     assert _strip(scoped) == _strip(plain)
+
+
+def test_moe_counters_ride_the_record_span(tmp_path):
+    """An MoE model's step writes its counters into the profile as one
+    event, named ``counters k=v ...``, inside ``engine.record``; summed
+    over the run they are EngineStats' totals.  The dense model of the
+    tests above writes none."""
+    model, cfg = build("granite-moe-1b-a400m", reduced=True)
+    pol = get_policy("binary32", decode_impl="paged")
+    params = model.init_params(jax.random.PRNGKey(0), pol)
+    eng, evs = _serve_traced(tmp_path, model, cfg, pol, params, slots=2,
+                             reqs=_requests(cfg, 3, 12, 4), prefill_chunk=0)
+    records = [(a, b) for n, a, b in evs if n == tracing.RECORD]
+    counters = [(n, a, b) for n, a, b in evs
+                if n.startswith(tracing.COUNTERS + " ")]
+    assert 0 < len(counters) <= len(records)
+    totals = {}
+    for n, a, b in counters:
+        assert any(lo <= a and b <= hi for lo, hi in records)
+        for kv in n.split()[1:]:
+            k, v = kv.split("=")
+            totals[k.split(".")[0]] = totals.get(k.split(".")[0], 0) + int(v)
+    assert totals == {"moe_routed_here": eng.stats.moe_routed_here,
+                      "moe_experts_hit": eng.stats.moe_experts_hit}
+    # summed over layers; every row of a decode step routes, idle too
+    assert eng.stats.moe_routed_here == cfg.n_layers * cfg.moe_topk * (
+        3 * 12 + 2 * eng.stats.target_steps)

@@ -211,3 +211,31 @@ def test_gemv_block_heuristic_and_byte_model():
     assert qmm_weight_bytes(64, 128, BINARY8, gated=True) == 2 * 64 * 128
     assert qmm_hbm_bytes(8, 64, 128, BINARY8, gated=True, bias=True) == (
         2 * 64 * 128 + 8 * 64 * 4 + 8 * 128 * 4 + 128 * 4)
+
+
+@pytest.mark.parametrize("fmt", [BINARY8, BINARY16ALT, BINARY32],
+                         ids=lambda f: f.name)
+@pytest.mark.parametrize("M,K,N,sizes", [
+    pytest.param(96, 256, 384, (10, 0, 30, 5, 0, 0, 20, 3), id="gaps-tail"),
+    pytest.param(600, 200, 300, (300, 1, 250), id="groups-share-tiles"),
+    pytest.param(16, 128, 256, (2,) * 8, id="every-group-one-tile"),
+    pytest.param(40, 128, 128, (0, 0, 0, 0), id="nothing-routed"),
+])
+def test_qmatmul_ragged_matches_dequantized_ragged_dot(fmt, M, K, N, sizes):
+    """The dropless expert kernel: rows sorted by group against each
+    group's packed block, vs ``lax.ragged_dot`` over the dequantized
+    stack; rows past the groups read 0."""
+    from repro.kernels.qmatmul import qmatmul_ragged
+    rng = np.random.default_rng(M + K)
+    x = jnp.asarray(rng.normal(size=(M, K)), jnp.float32)
+    wp = encode(jnp.asarray(rng.normal(size=(len(sizes), K, N)),
+                            jnp.float32), fmt)
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = np.asarray(qmatmul_ragged(x, wp, gs, fmt))
+    wd = decode(wp, fmt)
+    want = np.asarray(jax.lax.ragged_dot(x, wd, gs, precision="highest"))
+    n = sum(sizes)
+    scale = np.asarray(jax.lax.ragged_dot(jnp.abs(x), jnp.abs(wd), gs,
+                                          precision="highest")) + 1.0
+    _assert_oracle(got[:n], want[:n], scale[:n])
+    assert not got[n:].any()

@@ -345,8 +345,8 @@ def test_prefill_worker_writes_only_attention_layers(mixed_worker):
 
     request = types.SimpleNamespace(
         prompt=rng.integers(0, cfg.vocab, S).tolist())
-    _, one = worker._whole(worker.transport.params,
-                           make_batch(cfg, request))
+    _, one, _ = worker._whole(worker.transport.params,
+                              make_batch(cfg, request))
     for slot in (1, 2):
         states = view()
         want = [None if s is None else paged_cache.write_prefill(

@@ -86,9 +86,9 @@ taken = []
 orig = mm.moe_apply_sharded
 mm.moe_apply_sharded = lambda *a, **k: (taken.append(1), orig(*a, **k))[1]
 with compat.use_mesh(mesh):
-    y_d, _ = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg, pol))(p, x)
+    y_d, _, _ = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg, pol))(p, x)
     cfg2 = dataclasses.replace(cfg, moe_impl="shard_map")
-    y_s, _ = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg2, pol))(p, x)
+    y_s, _, _ = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg2, pol))(p, x)
 assert taken, "shard_map path not taken"
 np.testing.assert_allclose(np.asarray(y_s), np.asarray(y_d),
                            rtol=2e-5, atol=2e-5)

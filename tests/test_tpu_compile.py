@@ -11,8 +11,9 @@ require the compiled program to hold the Mosaic kernel
 
 Widths come from the published configs the engine serves: head_dim 64
 (granite-moe-1b-a400m) and 128 (yi-9b, mistral-nemo-12b), d_model 1024 and
-4096, the granite expert width 512 and the yi-9b FFN width 11008, in each
-packed container (binary8 -> uint8, binary16alt -> uint16, binary32 ->
+4096, the granite expert width 512 and the yi-9b FFN width 11008, and
+DeepSeek-V2-Lite's latent row (576 values in 640 lanes), in each packed
+container (binary8 -> uint8, binary16alt -> uint16, binary32 ->
 uint32).
 
 The topology is described inside a module fixture, never at import: only
@@ -99,6 +100,36 @@ def test_paged_decode_compiles(one_chip, fmt, dh, G, residuals):
         ((B, per_seq), jnp.int32))
 
 
+@pytest.mark.parametrize("fmt", [BINARY8, BINARY32],
+                         ids=lambda f: f.name)
+def test_latent_decode_compiles(one_chip, fmt):
+    """DeepSeek-V2-Lite's latent pool at the cell's size: 16 slots of 128
+    pages of 128 rows, 576 values padded to 640 lanes, 16 absorbed heads,
+    value width 512."""
+    from repro.kernels.latent_attention import latent_decode
+    B, H, W, page, per_seq = 16, 16, 640, 128, 128
+    fn = functools.partial(latent_decode, fmt=fmt, scale=0.1, v_dim=512,
+                           interpret=False)
+    text = _compile_for_chip(
+        lambda q, pool, n, t: fn(q, pool, lengths=n, block_tables=t),
+        one_chip, ((B, H, W), jnp.float32),
+        ((B * per_seq, page, W), fmt.container_dtype), ((B,), jnp.int32),
+        ((B, per_seq), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("S", [12288, 300])
+def test_latent_prefill_compiles(one_chip, S):
+    """DeepSeek-V2-Lite's un-absorbed prefill: 16 heads, q/k 192 wide, v
+    128, bf16, at the cell's longest prompt and at a ragged length."""
+    from repro.kernels.latent_attention import latent_prefill
+    fn = functools.partial(latent_prefill, scale=0.1, interpret=False)
+    text = _compile_for_chip(
+        fn, one_chip, ((1, S, 16, 192), jnp.bfloat16),
+        ((1, S, 16, 192), jnp.bfloat16), ((1, S, 16, 128), jnp.bfloat16))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
 @pytest.mark.parametrize("fmt,dh,G", ATTN_CASES + [
     pytest.param(None, 64, 2, id="float-dh64")])
 def test_flash_prefill_compiles(one_chip, fmt, dh, G):
@@ -129,6 +160,57 @@ def test_qmatmul_compiles(one_chip, fmt, a_shape, b_shape):
     fn = functools.partial(qmatmul, fmt_a=None, fmt_b=fmt, interpret=False)
     _compile_for_chip(fn, one_chip, (a_shape, jnp.float32),
                       (b_shape, fmt.container_dtype))
+
+
+@pytest.mark.parametrize("fmt,M,K,N,G", [
+    pytest.param(BINARY16ALT, 384, 1024, 512, 32, id="granite-decode-48x8"),
+    pytest.param(BINARY16ALT, 8192, 1024, 512, 32, id="granite-prefill-1k"),
+    pytest.param(BINARY8, 96, 2048, 1408, 8, id="dsv2lite-decode-16x6"),
+    pytest.param(BINARY8, 96, 1408, 2048, 8, id="dsv2lite-decode-out"),
+])
+def test_qmatmul_ragged_compiles(one_chip, fmt, M, K, N, G):
+    """The dropless expert layer's packed kernel: the pairs routed here,
+    sorted by expert, against the packed stack of the held experts."""
+    from repro.kernels.qmatmul import qmatmul_ragged
+    fn = functools.partial(qmatmul_ragged, fmt_b=fmt, interpret=False)
+    _compile_for_chip(fn, one_chip, ((M, K), jnp.float32),
+                      ((G, K, N), fmt.container_dtype), ((G,), jnp.int32))
+
+
+def test_packed_experts_stream_through_the_ragged_kernel(one_chip,
+                                                         monkeypatch):
+    """granite's expert layer under ``qmm_pallas`` over a packed store,
+    compiled for the chip: every expert matmul is the Mosaic kernel, and
+    no f32 copy of an expert stack is made (the XLA path would dequantize
+    each held stack on every call)."""
+    import dataclasses
+
+    from repro.core.qtensor import QTensor
+    from repro.core.policy import transprecision_policy
+    from repro.models.moe import moe_apply
+    from repro.models.registry import build
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, cfg = build("granite-moe-1b-a400m", reduced=False)
+    pol = transprecision_policy(mode="native", matmul_impl="qmm_pallas")
+    pol = dataclasses.replace(pol, formats={**pol.formats,
+                                            "ffn_w": BINARY16ALT})
+    E, d, ff = cfg.moe_experts, cfg.d_model, cfg.moe_d_ff
+    ct = BINARY16ALT.container_dtype
+
+    def layer(x, router, w_in, w_gate, w_out):
+        p = {"router": router,
+             "w_in": QTensor(w_in, BINARY16ALT),
+             "w_gate": QTensor(w_gate, BINARY16ALT),
+             "w_out": QTensor(w_out, BINARY16ALT)}
+        return moe_apply(p, x, cfg, pol)[0]
+
+    text = _compile_for_chip(
+        layer, one_chip, ((48, 1, d), jnp.bfloat16), ((d, E), jnp.float32),
+        ((E, d, ff), ct), ((E, d, ff), ct), ((E, ff, d), ct))
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert f"f32[{E},{d},{ff}]" not in text
+    assert f"f32[{E},{ff},{d}]" not in text
 
 
 @pytest.mark.parametrize("fmt", [BINARY8, BINARY16ALT, BINARY32],
@@ -240,3 +322,62 @@ def test_pool_writer_updates_pools_in_place(one_chip, n_kv, dh):
     shape = ",".join(map(str, pool))
     assert not re.search(rf"= f8e5m2\[{shape}\]\S* copy", text)
     assert compiled.memory_analysis().temp_size_in_bytes < math.prod(pool)
+
+
+def test_latent_decode_step_updates_pools_in_place(one_chip, monkeypatch):
+    """DeepSeek-V2-Lite's whole decode step at the ``dsv2lite.decode_16k``
+    size (27 layers, 16 slots x 16384, binary8 latent pools) compiled for
+    the chip: the step donates its state, so each of the 27 latent pools
+    is aliased to its own output and no pool-sized copy is left; what the
+    step adds beside weights and pools stays under two pools' bytes."""
+    import dataclasses
+    import re
+
+    import numpy as np
+
+    from repro import configs
+    from repro.engine.worker import DecodeWorker
+    from repro.kernels import paged_cache
+    from repro.models.registry import build_from_config
+    from repro.tuning.artifact import load_policy
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = configs.get("deepseek-v2-lite")
+    model = build_from_config(cfg)
+    pol = dataclasses.replace(load_policy("transprecision"),
+                              decode_impl="flash_pallas")
+    slots, page, per_seq = 16, 128, 128
+    spec = model.cache_spec(0)
+
+    def states():
+        return [paged_cache.init_pool(spec, slots, slots * per_seq, page,
+                                      per_seq, pol.dtype("kv_cache"))
+                for _ in range(cfg.n_layers)]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    args = on_chip((
+        jax.eval_shape(lambda k: model.init_params(k, pol),
+                       jax.random.PRNGKey(0)),
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32), jax.eval_shape(states),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    compiled = DecodeWorker(model, pol)._step.lower(*args).compile()
+    text = compiled.as_text()
+    pool = (slots * per_seq, page) + spec.stored_row
+    n_params = len(jax.tree.leaves(args[0]))
+    pairs = {(int(o), int(p)) for o, p in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}, may-alias\)", text.split("\n", 1)[0])}
+    # the state's leaves (pool, block tables, lengths per layer) follow
+    # the weights and the tokens, and lead the outputs
+    n_state = len(jax.tree.leaves(args[2]))
+    assert n_state == 3 * cfg.n_layers
+    assert pairs == {(i, n_params + 1 + i) for i in range(n_state)}
+    shape = ",".join(map(str, pool))
+    assert not re.search(rf"= f8e5m2\[{shape}\]\S* copy", text)
+    # one pool at a time is converted to the kernel's u8 view (the chip
+    # tiles f8e5m2 and u8 differently): the step's temporaries stay under
+    # two pools' bytes, where an undonated step would write all 27 again
+    pool_bytes = int(np.prod(pool))
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * pool_bytes
